@@ -20,16 +20,16 @@ exactly that:
 With ``tile_n`` set (tile-aligned deploy, the default of ``Engine.deploy``),
 the QTensor additionally carries the **fused single-launch layout**: every
 precision group's channel count is padded up to the ``tile_n`` output tile
-(zero rows, zero scales), the per-group packed buffers concatenate into one
-ragged 1-D byte buffer (``fused_packed``) with a static per-tile bit-width
-schedule (``tile_bits``), and ``matmul``/``conv2d`` run the whole
-multi-precision weight as ONE ``pallas_call``
-(kernels/quant_matmul.quant_matmul_fused_2d) — no per-group launches, no
-concat.  The schedule's tile walk order is chosen so that, whenever the
-canonical-order restore is tile-granular (single precision group, or
-already-sorted assignments), the restore folds into the kernel's identity
-output index map and ``fused_perm`` is ``None``; otherwise ``fused_perm``
-is a single output gather.
+(zero rows, zero scales), the per-tile packed blocks concatenate
+column-wise into one ragged ``(tile_n, W)`` byte buffer (``fused_packed``)
+with a static per-tile bit-width schedule (``tile_bits``), and
+``matmul``/``conv2d`` run the whole multi-precision weight as ONE
+``pallas_call`` (kernels/quant_matmul.quant_matmul_fused_2d) — no
+per-group launches, no concat.  The schedule's tile walk order is chosen
+so that, whenever the canonical-order restore is tile-granular (single
+precision group, or already-sorted assignments), the restore folds into
+the kernel's output layout and ``fused_perm`` is ``None``; otherwise
+``fused_perm`` is a single output gather.
 
 Because it is a **registered pytree** (arrays are leaves, geometry is aux
 data), a whole deployed model is just a params tree with ``QTensor`` leaves:
@@ -62,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import quantizers as qz
+from repro.dist import sharding as shd
 from repro.kernels import quant_matmul as qmk
 
 BACKENDS = ("jnp", "pallas", "pallas-pergroup")
@@ -88,13 +89,15 @@ def _fused_tile_layout(groups, tile_n: int, Kp: int, c_out: int,
     has folded into the kernel's identity output index map and the returned
     ``fused_perm`` is None; otherwise it is the (c_out,) output gather.
 
-    Returns ``(fused_packed 1-D uint8, fused_scales (T*tile_n,) f32,
-    fused_perm, tile_bits)``.
+    Returns ``(fused_packed (tile_n, W) uint8, fused_scales (T*tile_n,)
+    f32, fused_perm, tile_bits)``: tile ``t``'s packed bytes are the
+    column block ``quant_matmul.fused_tile_offsets(tile_bits, Kp)[t]``
+    onward.
 
     ``models/serving.init_deployed_linear`` carries a traced-safe sibling
     of this builder (jnp ops, schedule from static group sizes only, an
-    optional expert axis) for the vmap'd serving init — the two emit the
-    same layout contract (see the NOTE there); keep them in sync.
+    optional expert axis) for the vmap'd serving init; both pack through
+    ``quant_matmul.pack_fused_tiles``.
     """
     tiles = []
     dep_start = 0
@@ -114,8 +117,8 @@ def _fused_tile_layout(groups, tile_n: int, Kp: int, c_out: int,
     tiles.sort(key=lambda t: int(t[3][0]))
     tile_bits = tuple(t[0] for t in tiles)
     fused_packed = np.concatenate(
-        [np.asarray(qz.pack_int(jnp.asarray(q), b)).reshape(-1)
-         for b, q, _, _ in tiles])
+        [np.asarray(qmk.pack_fused_tiles(jnp.asarray(q), b, tile_n))
+         for b, q, _, _ in tiles], axis=1)
     fused_scales = np.concatenate([t[2] for t in tiles])
     tcol = np.concatenate([t[3] for t in tiles])
     if (tcol[:c_out] == np.arange(c_out)).all() and (tcol[c_out:] < 0).all():
@@ -143,7 +146,7 @@ class QTensor:
     kernel_shape: Optional[tuple] = None   # conv tail (c_in/g, kh, kw)
     restore_order: bool = True    # matmul outputs canonical channel order
     # -- fused single-launch layout (tile-aligned deploy; None = absent) ----
-    fused_packed: Optional[jnp.ndarray] = None   # 1-D uint8 ragged buffer
+    fused_packed: Optional[jnp.ndarray] = None   # (tile_n, W) uint8 ragged
     fused_scales: Optional[jnp.ndarray] = None   # (T * tile_n,) f32
     fused_perm: Optional[jnp.ndarray] = None     # (c_out,) i32 output gather;
     #                                              None = restore folded into
@@ -366,7 +369,6 @@ class QTensor:
         if self.experts is not None:
             return self._matmul_experts(x, compute_dtype, backend)
         if backend == "pallas" and self.fused_packed is not None:
-            from repro.dist import sharding as shd
             from repro.kernels import ops as kops
             ctx = shd.serving_ctx()
             if ctx is not None and ctx.model > 1:
@@ -377,25 +379,29 @@ class QTensor:
                         self.fused_perm, self.tile_bits, chunk, self.tile_n,
                         self.c_in, self.c_out, ctx.mesh,
                         out_dtype=compute_dtype, compute_dtype=compute_dtype)
-            return kops.quant_matmul_fused(
-                x, self.fused_packed, self.fused_scales, self.fused_perm,
-                self.tile_bits, self.tile_n, self.c_in, self.c_out,
-                out_dtype=compute_dtype, compute_dtype=compute_dtype)
+            return shd.replicated_kernel(
+                lambda x, fp, fs, perm: kops.quant_matmul_fused(
+                    x, fp, fs, perm, self.tile_bits, self.tile_n, self.c_in,
+                    self.c_out, out_dtype=compute_dtype,
+                    compute_dtype=compute_dtype))(
+                x, self.fused_packed, self.fused_scales, self.fused_perm)
         if backend in ("pallas", "pallas-pergroup"):
             from repro.kernels import ops as kops
-            c_in = self.c_in
+            c_in, bn = self.c_in, 128
             if self.tile_n is not None:
                 # fused-layout per-group buffers are packed at the common
-                # Kp byte width: feed the kernel the same padded columns
+                # Kp byte width: feed the kernel the same padded columns,
+                # and contract the fused kernel's (tile_n, Kp) dot shape
                 Kp = self.packed[-1].shape[-1] * qz.pack_factor(self.bits[-1])
                 widths = [(0, 0)] * (x.ndim - 1) + [(0, Kp - c_in)]
                 x = jnp.pad(x, widths)
-                c_in = Kp
+                c_in, bn = Kp, self.tile_n
 
             def gemm(b, p, s):
-                return kops.quant_matmul(x, p, s, b, c_in,
-                                         out_dtype=compute_dtype,
-                                         compute_dtype=compute_dtype)
+                return shd.replicated_kernel(
+                    lambda x, p, s: kops.quant_matmul(
+                        x, p, s, b, c_in, bn=bn, out_dtype=compute_dtype,
+                        compute_dtype=compute_dtype))(x, p, s)
         else:
             def gemm(b, p, s):
                 w = self._group_dense(b, p, s, compute_dtype)
@@ -423,7 +429,6 @@ class QTensor:
                 f"expert-stacked QTensor (experts={E}) takes x of shape "
                 f"(E, ..., c_in); got {x.shape}")
         if backend == "pallas" and self.fused_packed is not None:
-            from repro.dist import sharding as shd
             from repro.kernels import ops as kops
             ctx = shd.serving_ctx()
             if ctx is not None and ctx.model > 1 and E % ctx.model == 0:
@@ -432,25 +437,27 @@ class QTensor:
                     self.tile_bits, self.tile_n, self.c_in, self.c_out,
                     ctx.mesh, out_dtype=compute_dtype,
                     compute_dtype=compute_dtype)
-            return kops.quant_matmul_fused_batched(
-                x, self.fused_packed, self.fused_scales, self.fused_perm,
-                self.tile_bits, self.tile_n, self.c_in, self.c_out,
-                out_dtype=compute_dtype, compute_dtype=compute_dtype)
+            return shd.replicated_kernel(
+                lambda x, fp, fs, perm: kops.quant_matmul_fused_batched(
+                    x, fp, fs, perm, self.tile_bits, self.tile_n, self.c_in,
+                    self.c_out, out_dtype=compute_dtype,
+                    compute_dtype=compute_dtype))(
+                x, self.fused_packed, self.fused_scales, self.fused_perm)
         if backend in ("pallas", "pallas-pergroup"):
             from repro.kernels import ops as kops
-            c_in = self.c_in
+            c_in, bn = self.c_in, 128
             if self.tile_n is not None:
                 Kp = self.packed[-1].shape[-1] * qz.pack_factor(self.bits[-1])
                 widths = [(0, 0)] * (x.ndim - 1) + [(0, Kp - c_in)]
                 x = jnp.pad(x, widths)
-                c_in = Kp
+                c_in, bn = Kp, self.tile_n
 
             def gemm(b, p, s):
-                return jnp.stack([
-                    kops.quant_matmul(x[e], p[e], s[e], b, c_in,
-                                      out_dtype=compute_dtype,
-                                      compute_dtype=compute_dtype)
-                    for e in range(E)])
+                one = shd.replicated_kernel(
+                    lambda x, p, s: kops.quant_matmul(
+                        x, p, s, b, c_in, bn=bn, out_dtype=compute_dtype,
+                        compute_dtype=compute_dtype))
+                return jnp.stack([one(x[e], p[e], s[e]) for e in range(E)])
         else:
             def gemm(b, p, s):
                 w = self._group_dense(b, p, s, compute_dtype)  # (E, n, c_in)

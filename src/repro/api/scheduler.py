@@ -154,12 +154,11 @@ def _engine_jits(cfg, backend: str, sampling: smp.SamplingParams,
                 tok = smp.sample(logits, sampling, key)          # (B, 1)
                 return jnp.where(admit[:, None], tok, tok_old), caches
 
-            def _step(dp, tokens, caches, pos, live, key):
+            def _logits(dp, tokens, caches, pos, live):
                 """One decode tick: per-slot positions, live-masked cache."""
-                logits, caches = serving.decode_step(dp, cfg, tokens, caches,
-                                                     pos, backend, live=live,
-                                                     kv_bits=kv_bits)
-                return smp.sample(logits, sampling, key), caches
+                return serving.decode_step(dp, cfg, tokens, caches, pos,
+                                           backend, live=live,
+                                           kv_bits=kv_bits)
         else:
             def _admit(dp, batch, lens, admit, tok_old, caches, wp_flat,
                        key):
@@ -179,22 +178,28 @@ def _engine_jits(cfg, backend: str, sampling: smp.SamplingParams,
                 tok = smp.sample(logits, sampling, key)          # (B, 1)
                 return jnp.where(admit[:, None], tok, tok_old), caches
 
-            def _step(dp, tokens, caches, pos, live_write, pages, key):
+            def _logits(dp, tokens, caches, pos, live_write, pages):
                 """One paged decode tick: the ``(B, pages_per_slot)`` page
                 table routes every ring read/write; ``live_write`` also
                 masks rows whose write is suppressed for one tick (a
                 full-prefix hit whose last prompt position is already
                 cached in a shared page)."""
-                logits, caches = serving.decode_step(
+                return serving.decode_step(
                     dp, cfg, tokens, caches, pos, backend, live=live_write,
                     pages=pages, page_size=page_size, kv_bits=kv_bits)
-                return smp.sample(logits, sampling, key), caches
+
+        def _step(*args):
+            """The decode tick sampled: ``(*_logits args, key) ->
+            (next tokens, caches)``."""
+            logits, caches = _logits(*args[:-1])
+            return smp.sample(logits, sampling, args[-1]), caches
 
         ent = {"cfg": cfg,
                "admit": jax.jit(_meshed(_admit, cache_outs=(1,)),
                                 donate_argnums=(5,)),
                "step": jax.jit(_meshed(_step, cache_outs=(1,)),
-                               donate_argnums=(2,))}
+                               donate_argnums=(2,)),
+               "logits": jax.jit(_meshed(_logits, cache_outs=(1,)))}
 
         if speculate_k:
             # Speculative serving replaces the admission executable with a
@@ -447,6 +452,7 @@ class ServingEngine:
                            draft_kv_bits=draft_kv_bits,
                            mesh=self.mesh_ctx.mesh)
         self._admit_fn, self._step_fn = fns["admit"], fns["step"]
+        self._logits_fn = fns["logits"]
         if self.speculate_k:
             self._draft_fn = fns["draft_step"]
             self._verify_fn = fns["verify"]
@@ -887,7 +893,10 @@ class ServingEngine:
         return ({"kind": "prefill", "admitted": list(take)} if launched
                 else {"kind": "cached", "admitted": list(take)})
 
-    def _decode_tick(self) -> dict:
+    def _decode_args(self):
+        """The live slots and the decode launch's arguments, less the
+        sampling key; maps the page under each live slot's write
+        position first (idempotent until the tick runs)."""
         live = self._live.copy()
         if self.pool is not None:
             # lazily map the page under each live slot's write position —
@@ -905,6 +914,29 @@ class ServingEngine:
                 jnp.asarray(self._pos, jnp.int32), jnp.asarray(live_write))
         if self.pool is not None:
             args += (jnp.asarray(self._pages),)
+        return live, args
+
+    def decode_logits(self, tokens=None) -> jnp.ndarray:
+        """``(max_slots, vocab)`` logits of the next decode tick, from
+        the engine's decode program on its current state, without
+        advancing it.  ``tokens (max_slots, 1)`` replaces the pending
+        tokens, so two engines can score the same input."""
+        _, args = self._decode_args()
+        if tokens is not None:
+            tok = np.asarray(tokens, np.int32)
+            if self.mesh_ctx.is_active:
+                tok = self.mesh_ctx.put_replicated(tok)
+            args = (args[0], tok) + args[2:]
+        return self._logits_fn(*args)[0]
+
+    def lower_step(self):
+        """The engine's jitted decode step lowered at its current state
+        (``.compile().as_text()`` shows the program a tick runs)."""
+        _, args = self._decode_args()
+        return self._step_fn.lower(*args, self._key)
+
+    def _decode_tick(self) -> dict:
+        live, args = self._decode_args()
         self.tokens, self.caches = self._step_fn(*args, self._next_key())
         self.stats["decode_launches"] += 1
         n_live = int(live.sum())

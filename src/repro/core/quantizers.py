@@ -116,13 +116,30 @@ def quantize_act_int(x: jnp.ndarray, alpha: jnp.ndarray, bits: int
 # ---------------------------------------------------------------------------
 # Sub-byte packing.  TPU HBM is byte addressed; int4/int2 weights are stored
 # packed into uint8 (2 resp. 4 values per byte) and unpacked in VMEM by the
-# Pallas kernel (kernels/quant_matmul.py) or by the jnp fallback below.
+# Pallas kernels (kernels/quant_matmul.py, kernels/decode_attention.py) with
+# the same :func:`unpack_int` the jnp fallback uses.
 # Packing is along the LAST axis, which must be divisible by the pack factor.
+#
+# Layout: the packed bytes are cut into chunks of PACK_CHUNK bytes (the last
+# chunk may be shorter).  A chunk of ``w`` bytes holds ``w * f`` consecutive
+# values as ``f`` planes: value ``j * w + b`` of the chunk sits in byte ``b``
+# at bit ``j * bits``.  Unpacking is then shift/mask per plane and a lane
+# concatenation: no lane interleave, which the TPU compiler cannot lower.
+# A K block of a kernel grid that spans whole chunks is a contiguous byte
+# block, so gridded kernels unpack their own block alone.
 # ---------------------------------------------------------------------------
+
+PACK_CHUNK = 128
+
 
 def pack_factor(bits: int) -> int:
     assert bits in (2, 4, 8), bits
     return 8 // bits
+
+
+def _chunks(nbytes: int):
+    return [(c0, min(c0 + PACK_CHUNK, nbytes))
+            for c0 in range(0, nbytes, PACK_CHUNK)]
 
 
 def pack_int(q: jnp.ndarray, bits: int) -> jnp.ndarray:
@@ -137,36 +154,37 @@ def pack_int(q: jnp.ndarray, bits: int) -> jnp.ndarray:
     assert q.shape[-1] % f == 0, (q.shape, bits)
     mask = (1 << bits) - 1
     u = (q.astype(jnp.int32) & mask).astype(jnp.uint8)
-    u = u.reshape(*q.shape[:-1], q.shape[-1] // f, f)
-    return _pack_fold(u, bits)
+    out = []
+    for c0, c1 in _chunks(q.shape[-1] // f):
+        w = c1 - c0
+        byte = jnp.zeros(q.shape[:-1] + (w,), jnp.uint8)
+        for j in range(f):
+            plane = u[..., c0 * f + j * w: c0 * f + (j + 1) * w]
+            byte = byte | (plane << jnp.uint8(j * bits)).astype(jnp.uint8)
+        out.append(byte)
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=-1)
 
 
-def _pack_fold(u: jnp.ndarray, bits: int) -> jnp.ndarray:
-    """OR-fold the trailing pack axis of unsigned lanes into single bytes.
+def unpack_int(packed: jnp.ndarray, bits: int) -> jnp.ndarray:
+    """Inverse of :func:`pack_int`; returns int8 values, last axis expanded.
 
-    Value ``j`` of byte ``b`` sits at bit ``j * bits`` — the layout
-    ``unpack_int`` and the Pallas kernel's in-VMEM unpack both assume.
+    Written with ops the TPU compiler lowers (int32 shifts, static lane
+    slices, lane concatenation), so the Pallas kernels call it on their
+    VMEM blocks.
     """
-    out = jnp.zeros(u.shape[:-1], dtype=jnp.uint8)
-    for i in range(u.shape[-1]):
-        out = out | (u[..., i] << jnp.uint8(i * bits)).astype(jnp.uint8)
-    return out
-
-
-def unpack_int(packed: jnp.ndarray, bits: int, signed: bool = True) -> jnp.ndarray:
-    """Inverse of :func:`pack_int`; returns int8 values, last axis expanded."""
     if bits == 8:
-        return packed.view(jnp.int8) if signed else packed
+        return packed.view(jnp.int8)
     f = pack_factor(bits)
     mask = (1 << bits) - 1
-    shifts = jnp.arange(f, dtype=jnp.uint8) * bits
-    u = (packed[..., None] >> shifts) & mask  # (..., f) uint8
-    u = u.reshape(*packed.shape[:-1], packed.shape[-1] * f).astype(jnp.int8)
-    if signed:
-        # sign-extend from ``bits`` to 8
-        sign_bit = 1 << (bits - 1)
-        u = jnp.where(u >= sign_bit, u - (1 << bits), u).astype(jnp.int8)
-    return u
+    sign = 1 << (bits - 1)
+    u = packed.astype(jnp.int32)
+    planes = []
+    for j in range(f):
+        v = (u >> (j * bits)) & mask
+        planes.append(jnp.where(v >= sign, v - (1 << bits), v))
+    pieces = [p[..., c0:c1] for c0, c1 in _chunks(packed.shape[-1])
+              for p in planes]
+    return jnp.concatenate(pieces, axis=-1).astype(jnp.int8)
 
 
 def dequantize(q: jnp.ndarray, scale: jnp.ndarray, dtype=jnp.bfloat16) -> jnp.ndarray:

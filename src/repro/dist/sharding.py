@@ -74,6 +74,24 @@ def serving_ctx() -> Optional["MeshContext"]:
     return getattr(_state, "serving_ctx", None)
 
 
+def replicated_kernel(fn):
+    """``fn`` run whole on every device of the active serving mesh.
+
+    The TPU compiler cannot partition a Pallas (Mosaic) kernel: under a
+    multi-device mesh every kernel call must sit inside a ``shard_map``.
+    The operands of the kernels that ``serving_shardings`` leaves
+    replicated (per-group GEMMs, unsharded fused buffers, decode attention
+    over the replicated ring views) go in replicated, and each device runs
+    the same launch — the single-device result on every device.  Identity
+    outside ``serving_mesh``.
+    """
+    ctx = serving_ctx()
+    if ctx is None:
+        return fn
+    return jax.shard_map(fn, mesh=ctx.mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
+
 def replicate_serving(x):
     """Pin ``x`` replicated across the active serving mesh.
 
@@ -224,14 +242,15 @@ class ShardingRules:
                                        "; ".join([rule] + notes)))
         return spec
 
-    def _fused_spec(self, path: str, name: str, shape, qt) -> P:
+    def _fused_spec(self, path: str, leaf: str, shape, qt) -> P:
         """Sharding for a QTensor's fused ragged buffer / scale vector.
 
         The fused layout concatenates whole static-bit N-tiles, so the only
         legal shard boundary is a tile boundary:
 
-        * tensor parallel (1-D / layer-stacked weights): shard the byte axis
-          iff the tile schedule splits into ``model`` identical chunks
+        * tensor parallel (single / layer-stacked weights): shard the last
+          axis (byte columns of the buffer, channels of the scales) iff
+          the tile schedule splits into ``model`` identical chunks
           (``quant_matmul.tp_chunk``) — each device then owns whole tiles
           and runs the same shard_map program;
         * expert parallel (expert-stacked weights): shard the leading E axis
@@ -244,7 +263,7 @@ class ShardingRules:
         note = "replicate (fused: model axis = 1)"
         if m > 1 and qt.tile_bits is not None:
             if qt.experts is not None:
-                e_ax = len(shape) - 2
+                e_ax = len(shape) - 1 - qm.FUSED_LEAF_RANK[leaf]
                 if e_ax >= 0 and shape[e_ax] % m == 0:
                     axes[e_ax] = "model"
                     note = f"qtensor-fused-ep (E={shape[e_ax]} / model={m})"
@@ -259,7 +278,7 @@ class ShardingRules:
                     note = ("replicate (fused: tile schedule not periodic "
                             f"over model={m})")
         spec = P(*axes)
-        self.decisions.append(Decision(f"{path}/{name}", tuple(shape), spec,
+        self.decisions.append(Decision(f"{path}/{leaf}", tuple(shape), spec,
                                        note))
         return spec
 
@@ -277,7 +296,7 @@ class ShardingRules:
             name = _key_str(key_path[0]) if key_path else ""
             shape = tuple(getattr(leaf, "shape", ()))
             if name in ("fused_packed", "fused_scales"):
-                spec = self._fused_spec(path, sub, shape, qt)
+                spec = self._fused_spec(path, name, shape, qt)
             else:
                 # grouped buckets / permutations feed the jnp dequant GEMM,
                 # whose f32 matmul is not shard-invariant on CPU — keep them

@@ -1,3 +1,22 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the packed mixed-precision path.
+
+Every ``pallas_call`` in this package takes its ``interpret`` flag from
+:func:`interpret_mode`, the one place that decides whether a kernel is
+compiled by Mosaic or run by the Pallas interpreter.
+"""
+from __future__ import annotations
+
+import jax
+
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Whether a kernel traced now runs in the Pallas interpreter.
+
+    ``None`` (what every caller passes by default) is decided when the
+    kernel is traced, from the default backend: compiled on a TPU,
+    interpreted everywhere else.  An explicit bool wins: the v5e compile
+    tests pass ``False`` to lower for a described chip from a CPU process.
+    """
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"

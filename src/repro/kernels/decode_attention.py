@@ -35,32 +35,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# interpret=True executes the kernel body in Python on CPU (validation);
-# mirrors kernels/ops.INTERPRET for the matmul family.
-INTERPRET = True
-
-
-def _unpack_group(packed: jnp.ndarray, bits: int) -> jnp.ndarray:
-    """(S, nb) uint8 -> (S, nb * 8/bits) int8, sign-extended.
-
-    Same byte layout contract as ``core.quantizers.unpack_int`` and
-    ``quant_matmul._unpack_block``: value ``j`` of byte ``b`` at bit
-    ``j * bits``, interleaved back via stack+reshape.
-    """
-    if bits == 8:
-        return jax.lax.bitcast_convert_type(packed, jnp.int8)
-    f = 8 // bits
-    mask = (1 << bits) - 1
-    sign = 1 << (bits - 1)
-    parts = []
-    for i in range(f):
-        u = (packed >> (i * bits)) & mask                    # uint8 lanes
-        s = u.astype(jnp.int32)
-        s = jnp.where(s >= sign, s - (1 << bits), s)
-        parts.append(s.astype(jnp.int8))
-    stacked = jnp.stack(parts, axis=-1)                      # (S, nb, f)
-    return stacked.reshape(packed.shape[0], packed.shape[1] * f)
+from repro.core import quantizers as qz
+from repro.kernels import interpret_mode
 
 
 def _dequant_tile(packed, scales, bits, sizes, dtype):
@@ -72,28 +50,33 @@ def _dequant_tile(packed, scales, bits, sizes, dtype):
     outs, lo = [], 0
     for g, (b, n) in enumerate(zip(bits, sizes)):
         nb = n * b // 8
-        q = _unpack_group(packed[:, lo:lo + nb], b)
+        q = qz.unpack_int(packed[:, lo:lo + nb], b)
         lo += nb
         outs.append((q.astype(jnp.float32)
                      * scales[:, g:g + 1]).astype(dtype))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
 
 
-def _kernel(q_ref, kp_ref, ks_ref, vp_ref, vs_ref, pos_ref, o_ref, *,
+def _kernel(pos_ref, q_ref, kp_ref, ks_ref, vp_ref, vs_ref, o_ref, *,
             bits, sizes, compute_dtype):
-    q = q_ref[0, 0]                          # (rep, hd) compute_dtype
-    kf = _dequant_tile(kp_ref[0, 0], ks_ref[0, 0], bits, sizes,
+    q = q_ref[...]                           # (rep, hd) native dtype
+    kf = _dequant_tile(kp_ref[...], ks_ref[...], bits, sizes,
                        compute_dtype)        # (S, hd)
-    vf = _dequant_tile(vp_ref[0, 0], vs_ref[0, 0], bits, sizes,
+    vf = _dequant_tile(vp_ref[...], vs_ref[...], bits, sizes,
                        compute_dtype)
-    S, hd = kf.shape
-    # same promotion semantics as the reference einsum: result_type(q, kf)
-    # first (bf16 q -> rounded bf16 scores, f32 q -> f32), THEN the f32 cast
-    s = jnp.dot(q, kf.T).astype(jnp.float32) / math.sqrt(hd)   # (rep, S)
-    valid = jnp.arange(S)[None, :] <= pos_ref[0]
+    hd = kf.shape[1]
+    # f32 scores straight from the f32 accumulator (the MXU needs one).
+    # The reference einsum's bf16 scores are widened to f32 in the same
+    # program, and XLA keeps them at the accumulator's precision (excess
+    # precision), so both paths score alike for bf16 and f32 queries.
+    s = jnp.dot(q, kf.T, preferred_element_type=jnp.float32
+                ) / math.sqrt(hd)                               # (rep, S)
+    valid = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+             <= pos_ref[pl.program_id(0)])
     s = jnp.where(valid, s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1).astype(compute_dtype)
-    o_ref[0, 0] = jnp.dot(w, vf)
+    o_ref[...] = jnp.dot(w, vf, preferred_element_type=jnp.float32
+                         ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "sizes", "out_dtype",
@@ -102,7 +85,7 @@ def decode_attention(q: jnp.ndarray, k_packed: jnp.ndarray,
                      k_scales: jnp.ndarray, v_packed: jnp.ndarray,
                      v_scales: jnp.ndarray, pos: jnp.ndarray,
                      bits: tuple, sizes: tuple, out_dtype=jnp.bfloat16,
-                     interpret: bool = INTERPRET) -> jnp.ndarray:
+                     interpret: bool | None = None) -> jnp.ndarray:
     """Fused packed-cache GQA decode attention.
 
     ``q (B, KV, rep, hd)`` query head groups in their NATIVE dtype (f32
@@ -119,17 +102,16 @@ def decode_attention(q: jnp.ndarray, k_packed: jnp.ndarray,
     assert sum(sizes) == hd and sum(n * b // 8 for b, n in
                                     zip(bits, sizes)) == NB, (bits, sizes,
                                                               hd, NB)
-    ring = lambda nf: pl.BlockSpec((1, 1, S, nf), lambda b, g: (b, g, 0, 0))
+    ring = lambda nf: pl.BlockSpec((None, None, S, nf),
+                                   lambda b, g: (b, g, 0, 0))
+    head = pl.BlockSpec((None, None, rep, hd), lambda b, g: (b, g, 0, 0))
     return pl.pallas_call(
         functools.partial(_kernel, bits=bits, sizes=sizes,
                           compute_dtype=out_dtype),
         grid=(B, KV),
-        in_specs=[
-            pl.BlockSpec((1, 1, rep, hd), lambda b, g: (b, g, 0, 0)),
-            ring(NB), ring(G), ring(NB), ring(G),
-            pl.BlockSpec((1,), lambda b, g: (b,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, hd), lambda b, g: (b, g, 0, 0)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),   # pos, whole
+                  head, ring(NB), ring(G), ring(NB), ring(G)],
+        out_specs=head,
         out_shape=jax.ShapeDtypeStruct((B, KV, rep, hd), out_dtype),
-        interpret=interpret,
-    )(q, k_packed, k_scales, v_packed, v_scales, pos)
+        interpret=interpret_mode(interpret),
+    )(pos, q, k_packed, k_scales, v_packed, v_scales)

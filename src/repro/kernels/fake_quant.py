@@ -8,10 +8,10 @@ plain forward.  This kernel computes
     out[n, k] = sum_p gamma_hat[n, p] * FQ(w[n, k]; alpha[n], p)
 
 in a single pass: one W read, one OUT write, everything else in VMEM.  This
-is the "fused fake-quant" beyond-paper optimization logged in EXPERIMENTS.md
-§Perf (it attacks the memory roofline term of the train_4k cells).
+is the "fused fake-quant" beyond-paper optimization (it attacks the memory
+roofline term of the search phase).
 
-Grid (N/bn, K/bk); blocks: w (bn, bk), gamma_hat (bn, P), alpha (bn,).
+Grid (N/bn, K/bk); blocks: w (bn, bk), gamma_hat (bn, P), alpha (bn, 1).
 The P loop is unrolled (|P_W| = 3).
 """
 from __future__ import annotations
@@ -22,10 +22,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 def _kernel(w_ref, g_ref, a_ref, o_ref, *, bitwidths: tuple[int, ...]):
     w = w_ref[...].astype(jnp.float32)                    # (bn, bk)
-    a = jnp.maximum(a_ref[...].astype(jnp.float32), 1e-6)[:, None]
+    a = jnp.maximum(a_ref[...].astype(jnp.float32), 1e-6)  # (bn, 1)
     acc = jnp.zeros_like(w)
     for i, bits in enumerate(bitwidths):
         half = (1 << (bits - 1)) - 1
@@ -38,7 +40,7 @@ def _kernel(w_ref, g_ref, a_ref, o_ref, *, bitwidths: tuple[int, ...]):
 
 def fused_mix_2d(w: jnp.ndarray, gamma_hat: jnp.ndarray, alpha: jnp.ndarray,
                  bitwidths=(2, 4, 8), *, bn: int = 256, bk: int = 512,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool | None = None) -> jnp.ndarray:
     """w (N, K), gamma_hat (N, |P|), alpha (N,) -> mixed weights (N, K) f32.
 
     Forward-only fused path (the VJP falls back to the reference expression —
@@ -56,9 +58,9 @@ def fused_mix_2d(w: jnp.ndarray, gamma_hat: jnp.ndarray, alpha: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bn, bk), lambda i, j: (i, j)),
             pl.BlockSpec((bn, len(bitwidths)), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
+            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bn, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, K), jnp.float32),
-        interpret=interpret,
-    )(w, gamma_hat, alpha)
+        interpret=interpret_mode(interpret),
+    )(w, gamma_hat, alpha.reshape(N, 1))

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 # K ceiling for exact int32 accumulation: K * 127 * 127 <= 2^31 - 1.
 K_INT32_EXACT_MAX = (2 ** 31 - 1) // (127 * 127)
 
@@ -82,8 +84,8 @@ def scaled_int8_mm_ref(a: jnp.ndarray, b: jnp.ndarray, sa: jnp.ndarray,
 def _int8_mm_kernel(a_ref, b_ref, sa_ref, sb_ref, o_ref):
     acc = jax.lax.dot_general(a_ref[...], b_ref[...], _DIM_NUMS,
                               preferred_element_type=jnp.int32)
-    out = acc.astype(jnp.float32) * sa_ref[...].astype(jnp.float32)[:, None]
-    o_ref[...] = out * sb_ref[...].astype(jnp.float32)[None, :]
+    out = acc.astype(jnp.float32) * sa_ref[...]             # (bm, 1)
+    o_ref[...] = out * sb_ref[...]                          # (1, bn)
 
 
 def _pad_axis(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -111,8 +113,8 @@ def scaled_int8_mm(a: jnp.ndarray, b: jnp.ndarray, sa: jnp.ndarray,
 
     The int8 training GEMM with the dequant epilogue fused into the kernel.
     ``backend="jnp"`` runs the (bitwise-identical) reference — used under
-    vmap and as the CI cross-check.  ``interpret`` defaults to the global
-    ``ops.INTERPRET`` flag (CPU validation vs real TPU lowering).
+    vmap and as the CI cross-check.  ``interpret=None`` lets
+    :func:`repro.kernels.interpret_mode` decide from the platform.
     """
     M, K = a.shape
     N = b.shape[0]
@@ -124,9 +126,6 @@ def scaled_int8_mm(a: jnp.ndarray, b: jnp.ndarray, sa: jnp.ndarray,
             f"(max {K_INT32_EXACT_MAX}); shard the contraction first")
     if backend == "jnp":
         return scaled_int8_mm_ref(a, b, sa, sb)
-    if interpret is None:
-        from repro.kernels import ops
-        interpret = ops.INTERPRET
     bm_, bn_ = _pick_tile(M, bm), _pick_tile(N, bn)
     # zero padding is exact: padded rows/cols accumulate zeros and their
     # (zero) scales make the epilogue a no-op; K pads to the MXU lane width
@@ -142,13 +141,13 @@ def scaled_int8_mm(a: jnp.ndarray, b: jnp.ndarray, sa: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm_, Kp), lambda i, j: (i, 0)),
             pl.BlockSpec((bn_, Kp), lambda i, j: (j, 0)),
-            pl.BlockSpec((bm_,), lambda i, j: (i,)),
-            pl.BlockSpec((bn_,), lambda i, j: (j,)),
+            pl.BlockSpec((bm_, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bn_), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        interpret=interpret,
-    )(ap, bp, sap, sbp)
+        interpret=interpret_mode(interpret),
+    )(ap, bp, sap.reshape(Mp, 1), sbp.reshape(1, Np))
     return out[:M, :N]
 
 

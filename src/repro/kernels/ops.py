@@ -1,5 +1,5 @@
 """jit'd public wrappers around the Pallas kernels: padding, batching,
-backend/interpret selection.
+backend selection.
 
 ``quant_matmul_fused`` is the deployed hot path: ONE ``pallas_call`` for a
 whole multi-precision weight (tile-aligned fused layout, see
@@ -14,20 +14,14 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.core
+import jax.extend.core
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import quantizers as qz
 from repro.kernels import fake_quant as fq_kernel
 from repro.kernels import quant_conv as qc_kernel
 from repro.kernels import quant_matmul as qm_kernel
-
-# interpret=True executes the kernel body in Python on CPU (validation);
-# on a real TPU runtime set repro_kernels_interpret=False via this flag.
-INTERPRET = True
-
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
     size = x.shape[axis]
@@ -73,7 +67,7 @@ def quant_matmul(x: jnp.ndarray, packed: jnp.ndarray, scale: jnp.ndarray,
     bk_ = qm_kernel.pick_bk(Kp, f, bk)
     y = qm_kernel.quant_matmul_2d(x2, packed_p, scale_p, bits, bm=bm_,
                                   bn=min(bn, packed_p.shape[0]), bk=bk_,
-                                  interpret=INTERPRET, out_dtype=out_dtype,
+                                  out_dtype=out_dtype,
                                   compute_dtype=compute_dtype)
     return y[:M, :N].reshape(*lead, N)
 
@@ -119,7 +113,7 @@ def quant_matmul_fused(x: jnp.ndarray, fused_packed: jnp.ndarray,
     x2 = _pad_to(x2, 0, bm_)
     y = qm_kernel.quant_matmul_fused_2d(
         x2, fused_packed, fused_scales, tile_bits, Kp=Kp, tile_n=tile_n,
-        bm=bm_, interpret=INTERPRET, out_dtype=out_dtype,
+        bm=bm_, out_dtype=out_dtype,
         compute_dtype=compute_dtype)
     y = y[:M]
     if fused_perm is not None:
@@ -142,14 +136,15 @@ def quant_matmul_fused_batched(x: jnp.ndarray, fused_packed: jnp.ndarray,
     ONE kernel launch — the packed replacement for
     ``einsum("ecd,efd->ecf", x, dense_expert_stack)``.
 
-    ``fused_packed (E, bytes)`` / ``fused_scales (E, T * tile_n)`` are the
-    per-expert buffers of the shared static tile schedule
+    ``fused_packed (E, tile_n, W)`` / ``fused_scales (E, T * tile_n)``
+    are the per-expert buffers of the shared static tile schedule
     (``models/serving.init_deployed_linear(expert_axis=E)``); the grid adds
     a leading E axis (kernels/quant_matmul.quant_matmul_fused_3d).  The
-    kernel dequantizes each weight tile in VMEM **before** the MXU dot, so
-    at f32 compute the output is bit-exact with the dense einsum reference
-    over ``dequantize()`` — HBM weight traffic stays the packed sub-byte
-    bytes.  ``fused_perm`` gathers the output channels exactly as in
+    kernel dequantizes the weight tiles in VMEM **before** the MXU dot, so
+    at f32 compute the output matches the dense einsum reference over
+    ``dequantize()`` (bit for bit on the CPU interpreter when one grid step
+    spans the whole output; see the kernel) — HBM weight traffic stays the
+    packed sub-byte bytes.  ``fused_perm`` gathers the output channels exactly as in
     :func:`quant_matmul_fused` (None = restore folded into the walk order).
     """
     E = fused_packed.shape[0]
@@ -171,7 +166,7 @@ def quant_matmul_fused_batched(x: jnp.ndarray, fused_packed: jnp.ndarray,
     x2 = _pad_to(x2, 1, bm_)
     y = qm_kernel.quant_matmul_fused_3d(
         x2, fused_packed, fused_scales, tile_bits, Kp=Kp, tile_n=tile_n,
-        bm=bm_, interpret=INTERPRET, out_dtype=out_dtype,
+        bm=bm_, out_dtype=out_dtype,
         compute_dtype=compute_dtype)
     y = y[:, :M]
     if fused_perm is not None:
@@ -192,7 +187,8 @@ def quant_matmul_fused_tp(x: jnp.ndarray, fused_packed: jnp.ndarray,
                           out_dtype=jnp.float32, bm: int = 128,
                           compute_dtype=jnp.float32) -> jnp.ndarray:
     """Tensor-parallel :func:`quant_matmul_fused`: the fused ragged buffer
-    and its scales are sharded along the N-tile schedule (``mesh[axis]``
+    (on its byte-column axis) and its scales are sharded along the N-tile
+    schedule (``mesh[axis]``
     identical chunks, see ``quant_matmul.tp_chunk``), each device runs the
     SAME single-launch program over its own whole static-bit tiles, and the
     output concatenates along N.  Per-device compute is the unmodified int
@@ -218,12 +214,11 @@ def quant_matmul_fused_tp(x: jnp.ndarray, fused_packed: jnp.ndarray,
     def body(xs, fp, fs):
         return qm_kernel.quant_matmul_fused_2d(
             xs, fp, fs, chunk, Kp=Kp, tile_n=tile_n, bm=bm_,
-            interpret=INTERPRET, out_dtype=out_dtype,
-            compute_dtype=compute_dtype)
+            out_dtype=out_dtype, compute_dtype=compute_dtype)
 
-    y = shard_map(body, mesh=mesh,
-                  in_specs=(P(), P(axis), P(axis)),
-                  out_specs=P(None, axis), check_rep=False)(
+    y = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P(), P(None, axis), P(axis)),
+                      out_specs=P(None, axis), check_vma=False)(
         x2, fused_packed, fused_scales)
     y = y[:M]
     if fused_perm is not None:
@@ -272,12 +267,11 @@ def quant_matmul_fused_batched_ep(x: jnp.ndarray, fused_packed: jnp.ndarray,
     def body(xs, fp, fs):
         return qm_kernel.quant_matmul_fused_3d(
             xs, fp, fs, tile_bits, Kp=Kp, tile_n=tile_n, bm=bm_,
-            interpret=INTERPRET, out_dtype=out_dtype,
-            compute_dtype=compute_dtype)
+            out_dtype=out_dtype, compute_dtype=compute_dtype)
 
-    y = shard_map(body, mesh=mesh,
-                  in_specs=(P(axis), P(axis), P(axis)),
-                  out_specs=P(axis), check_rep=False)(
+    y = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P(axis), P(axis), P(axis)),
+                      out_specs=P(axis), check_vma=False)(
         x2, fused_packed, fused_scales)
     y = y[:, :M]
     if fused_perm is not None:
@@ -358,9 +352,9 @@ def count_pallas_launches(fn, *args, **kwargs) -> int:
                 stack.extend(u)
             elif isinstance(u, dict):
                 stack.extend(u.values())
-            elif isinstance(u, jax.core.ClosedJaxpr):
+            elif isinstance(u, jax.extend.core.ClosedJaxpr):
                 yield u.jaxpr
-            elif isinstance(u, jax.core.Jaxpr):
+            elif isinstance(u, jax.extend.core.Jaxpr):
                 yield u
 
     def walk(jpr) -> int:
@@ -387,8 +381,7 @@ def fused_mix(w: jnp.ndarray, gamma_hat: jnp.ndarray, alpha: jnp.ndarray,
         wp = _pad_to(_pad_to(w, 0, 256), 1, 512)
         gp = _pad_to(gamma_hat, 0, 256)
         ap = jnp.maximum(_pad_to(alpha, 0, 256), 1e-6)
-        out = fq_kernel.fused_mix_2d(wp, gp, ap, bitwidths,
-                                     interpret=INTERPRET)
+        out = fq_kernel.fused_mix_2d(wp, gp, ap, bitwidths)
         return out[:N, :K]
     return fq_kernel.fused_mix_2d(w, gamma_hat, alpha, bitwidths, bn=bn,
-                                  bk=bk, interpret=INTERPRET)
+                                  bk=bk)

@@ -3,7 +3,9 @@ continuous batching over a paged KV cache (repro.api.ServingEngine).
 
 The deployed weights are the Sec. III-C output: channels reordered and
 grouped by searched bit-width, packed sub-byte, consumed as per-precision
-sub-GEMMs (kernels/quant_matmul.py on TPU; jnp fallback on CPU).  The
+sub-GEMMs: ``--backend pallas`` runs the Pallas kernels of
+kernels/quant_matmul.py (compiled on a TPU, interpreted elsewhere),
+``--backend jnp`` the plain-jnp reference.  The
 launcher synthesizes a staggered-arrival trace (requests arriving over
 time with ragged prompt/output lengths) and serves it through the paged
 slot pool: finished slots are reclaimed and refilled without re-jitting,
@@ -33,6 +35,7 @@ import numpy as np
 from repro.api.scheduler import Request, ServingEngine
 from repro.config import ARCH_IDS, get_config
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh, make_production_mesh
 from repro.models import serving
 
@@ -204,6 +207,7 @@ def main() -> None:
                         "trace (drain-on-death demo; requires --mesh)")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

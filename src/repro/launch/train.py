@@ -29,6 +29,7 @@ from repro.config import ARCH_IDS, get_config
 from repro.core import mixedprec as mp
 from repro.data import pipeline as pipe
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh, make_production_mesh
 from repro.models import transformer as tfm
 from repro.train import checkpoint as ckpt_mod
@@ -76,6 +77,7 @@ def main() -> None:
                    help="use the 16x16 mesh (requires 256 devices)")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -349,10 +349,10 @@ def gqa_decode(p: dict, cfg, x: jnp.ndarray, cache: dict,
         # then promotes exactly like the reference einsum, so fused and jnp
         # paths stay token-identical
         qg = q.transpose(0, 2, 1, 3).reshape(B, KV, rep, hd)
-        o = datt_kernel.decode_attention(qg, ki, ksc, vi, vsc,
-                                         pos, kv_spec.bits, kv_spec.sizes,
-                                         out_dtype=cd,
-                                         interpret=datt_kernel.INTERPRET)
+        o = shd.replicated_kernel(
+            lambda *a: datt_kernel.decode_attention(
+                *a, kv_spec.bits, kv_spec.sizes, out_dtype=cd))(
+            qg, ki, ksc, vi, vsc, pos)
         return dq_linear(o.reshape(B, 1, H * hd), p["wo"]), cache
     if kv_spec is None:
         kf = (ki.astype(jnp.float32) * ksc).astype(cd)
@@ -457,10 +457,10 @@ def _gqa_decode_multi(p: dict, cfg, x: jnp.ndarray, cache: dict,
     if kv_spec is not None and backend == "pallas":
         for j in range(W):
             qg = q[:, j:j + 1].transpose(0, 2, 1, 3).reshape(B, KV, rep, hd)
-            o = datt_kernel.decode_attention(qg, ki, ksc, vi, vsc,
-                                             posk[:, j], kv_spec.bits,
-                                             kv_spec.sizes, out_dtype=cd,
-                                             interpret=datt_kernel.INTERPRET)
+            o = shd.replicated_kernel(
+                lambda *a: datt_kernel.decode_attention(
+                    *a, kv_spec.bits, kv_spec.sizes, out_dtype=cd))(
+                qg, ki, ksc, vi, vsc, posk[:, j])
             outs.append(o.reshape(B, 1, H * hd))
         return dq_linear(jnp.concatenate(outs, axis=1), p["wo"]), cache
     if kv_spec is None:

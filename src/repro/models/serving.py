@@ -69,14 +69,12 @@ def init_deployed_linear(key, c_in: int, c_out: int, cfg,
 
     NOTE this is the traced-safe sibling of
     ``repro.api.qtensor._fused_tile_layout`` (the numpy builder behind
-    ``QTensor.from_assignment``): both emit the contract consumed by
-    ``kernels/quant_matmul.fused_tile_offsets`` and the fused kernels —
-    tile segments contiguous in walk order, per-tile bytes
-    ``tile_n * Kp * b/8``, zero scales on padding rows, ``fused_perm``
-    None iff padding lands only past ``c_out``.  Here the assignment is
+    ``QTensor.from_assignment``): both pack tiles through
+    ``kernels/quant_matmul.pack_fused_tiles`` and concatenate them in walk
+    order, with zero scales on padding rows and ``fused_perm`` None iff
+    padding lands only past ``c_out``.  Here the assignment is
     group-contiguous and ascending-bit, so the walk order is the natural
-    group order and no tile sort is needed; change the layout in BOTH
-    builders or the kernel asserts / parity harnesses will fail.
+    group order and no tile sort is needed.
     """
     from repro.api.qtensor import _auto_tile_n
     from repro.kernels import quant_matmul as qmk
@@ -112,9 +110,7 @@ def init_deployed_linear(key, c_in: int, c_out: int, cfg,
             qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0)))
             sp = jnp.pad(scale[..., 0].astype(jnp.float32),
                          ((0, 0), (0, pad)))
-            # tiles are contiguous row runs, so the group's row-major bytes
-            # ARE its tile segments in walk order
-            fused_p.append(qz.pack_int(qp, b).reshape(E, -1))
+            fused_p.append(qmk.pack_fused_tiles(qp, b, tile_n))
             fused_s.append(sp)
             tile_bits += [b] * ((n + pad) // tile_n)
             tcol += list(range(dep, dep + n)) + [-1] * pad

@@ -99,7 +99,12 @@ def test_qtensor_conv_kernel_shape_roundtrip():
     assert qt.kernel_shape == (3, 3, 3)
     dense = qt.dense()
     assert dense.shape == w.shape
-    np.testing.assert_allclose(np.asarray(dense), w, atol=1e-2, rtol=1e-2)
+    # the symmetric 8-bit quantizer's guarantee: every weight within half a
+    # step, alpha / (2 * 127), of its channel's grid (plus f32 roundoff)
+    half_step = (alpha / (2 * 127))[:, None, None, None]
+    err = np.abs(np.asarray(dense) - w)
+    assert (err <= half_step + np.finfo(np.float32).eps * np.abs(w)).all(), \
+        (err / half_step).max()
 
 
 # ---------------------------------------------------------------------------

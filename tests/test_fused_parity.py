@@ -61,6 +61,11 @@ CASES = [
      lambda rng, n: np.asarray([2] + [8] * (n - 1))),
     ("two-bit-heavy-tiny-K", 9, 5, 4,
      lambda rng, n: rng.choice([2, 4], size=n, p=[0.8, 0.2])),
+    # 128-wide tiles: one tile per grid step, and 128-lane-aligned column
+    # blocks (K=512), so same-width tiles share a branch at a column offset
+    # computed from the grid index
+    ("128-wide-tile-runs", 768, 512, 128,
+     lambda rng, n: np.repeat([2, 4, 8], n // 3)),
 ]
 
 

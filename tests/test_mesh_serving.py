@@ -308,7 +308,6 @@ def test_cache_shardings_slot_axis():
 def test_count_launches_through_shard_map_and_pjit():
     """The launch counter must see kernels hidden under shard_map and
     nested-jit (pjit) sub-jaxprs — one program-level count each."""
-    from jax.experimental.shard_map import shard_map
 
     qt = _uniform_qtensor(11, 32, 16, 8, tile_n=16)
     x = jnp.zeros((4, 16), jnp.float32)
@@ -325,14 +324,14 @@ def test_count_launches_through_shard_map_and_pjit():
     mesh = _mesh(1, 1)
 
     def sharded(xv):
-        return shard_map(fused, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                         check_rep=False)(xv)
+        return jax.shard_map(fused, mesh=mesh, in_specs=(P(),),
+                             out_specs=P(), check_vma=False)(xv)
 
     assert ops.count_pallas_launches(sharded, x) == 1
     # two launches under one shard_map still count as two
     def sharded2(xv):
-        return shard_map(lambda v: fused(v) + fused(v), mesh=mesh,
-                         in_specs=(P(),), out_specs=P(),
-                         check_rep=False)(xv)
+        return jax.shard_map(lambda v: fused(v) + fused(v), mesh=mesh,
+                             in_specs=(P(),), out_specs=P(),
+                             check_vma=False)(xv)
 
     assert ops.count_pallas_launches(sharded2, x) == 2

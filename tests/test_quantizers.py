@@ -80,7 +80,8 @@ def test_pact_alpha_gradient():
 
 @pytest.mark.parametrize("seed", range(6))
 @pytest.mark.parametrize("bits", BITS)
-@pytest.mark.parametrize("k", [8, 16, 64, 256])
+# k=1040 spans several PACK_CHUNK byte chunks plus a short tail chunk
+@pytest.mark.parametrize("k", [8, 16, 64, 256, 1040])
 def test_pack_unpack_roundtrip(seed, bits, k):
     rng = np.random.default_rng(seed * 97 + bits)
     half = (1 << (bits - 1)) - 1

@@ -59,6 +59,7 @@ class SamplingParams:
 GREEDY = SamplingParams()
 
 
+@jax.named_scope("sample")
 def sample(logits: jnp.ndarray, params: SamplingParams = GREEDY,
            key: Optional[jax.Array] = None) -> jnp.ndarray:
     """Sample token ids from ``logits (..., V)`` -> int32 ``(...)``.
@@ -97,6 +98,7 @@ def _dist(logits: jnp.ndarray, params: SamplingParams) -> jnp.ndarray:
     return jax.nn.softmax(lg, axis=-1)
 
 
+@jax.named_scope("sample")
 def speculative_accept(draft_tokens: jnp.ndarray, draft_logits: jnp.ndarray,
                        verify_logits: jnp.ndarray,
                        params: SamplingParams = GREEDY,

@@ -53,7 +53,9 @@ is an explicit opt-in.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import math
+import time
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -86,10 +88,94 @@ class Request:
 
 @dataclasses.dataclass
 class RequestOutput:
+    """A finished request.  The four times are ``time.perf_counter()``
+    readings: ``submit`` returned, the request left the admission queue
+    for a slot, its first token was read back, it finished."""
     rid: int
     tokens: np.ndarray              # (n_generated,) int32, eos included
     prompt_len: int
     finish_reason: str              # "length" | "eos"
+    submitted_s: float
+    admitted_s: float
+    first_token_s: float
+    finished_s: float
+
+
+# -- tracing -----------------------------------------------------------------
+# Every span is a ``jax.profiler.TraceAnnotation``: it lands on the host
+# plane of a running profiler session (beside the device ops, on one clock)
+# and is a no-op otherwise.  Spans that time a phase also add their
+# duration to a cumulative counter in ``ServingEngine.stats``, so the
+# counters exist whether or not anything is profiling.
+_clock = time.perf_counter
+_PHASES = ("plan", "launch", "sync", "record")
+_TICK_KINDS = ("admit", "decode", "speculative")
+
+
+class _Span:
+    """One profiler span that adds its wall time to ``stats[key]``.
+
+    ``close`` may end the span before its ``with`` block does, naming the
+    counter and the attributes learnt meanwhile (a step's plan phase learns
+    its tick kind last); the block's exit is then a no-op.
+    """
+    __slots__ = ("_stats", "_key", "_ann", "_t0")
+
+    def __init__(self, stats: dict, name: str, key: Optional[str] = None,
+                 **attrs):
+        self._stats, self._key = stats, key
+        self._ann = jax.profiler.TraceAnnotation(name, **attrs)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = _clock()
+        return self
+
+    def close(self, key: Optional[str] = None, **attrs) -> None:
+        if self._ann is None:
+            return
+        dt = _clock() - self._t0
+        if attrs:
+            self._ann.set_metadata(**attrs)
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+        key = key or self._key
+        if key is not None:
+            self._stats[key] = self._stats.get(key, 0.0) + dt
+
+    def __exit__(self, *exc):
+        self.close()
+
+
+class _GCPauses:
+    """Pauses of Python's cyclic garbage collector, process-wide: one
+    ``gc.callbacks`` hook opens a ``gc`` span (attribute ``generation``)
+    when a collection starts and, when it stops, closes it and adds to the
+    totals that every engine reads."""
+    pause_s = 0.0
+    collections = 0
+    _open: list = []
+
+    @classmethod
+    def hook(cls, phase: str, info: dict) -> None:
+        if phase == "start":
+            ann = jax.profiler.TraceAnnotation(
+                "gc", generation=info["generation"])
+            ann.__enter__()
+            cls._open.append((ann, _clock()))
+        elif cls._open:
+            ann, t0 = cls._open.pop()
+            cls.pause_s += _clock() - t0
+            cls.collections += 1
+            ann.__exit__(None, None, None)
+
+
+# installed once per process: a module reload replaces the hook, engines
+# never add one
+gc.callbacks[:] = [cb for cb in gc.callbacks
+                   if getattr(cb, "__qualname__", None) != "_GCPauses.hook"
+                   or getattr(cb, "__module__", None) != __name__]
+gc.callbacks.append(_GCPauses.hook)
 
 
 # Module-level jitted admission/step executables, keyed on (cfg id, backend,
@@ -147,6 +233,7 @@ def _engine_jits(cfg, backend: str, sampling: smp.SamplingParams,
                 ring = jax.tree_util.tree_map(jnp.zeros_like, caches)
                 emb = serving.embed_caches(pf, ring)
 
+                @jax.named_scope("kv_write")
                 def merge(new, old):  # stacked cache leaves: batch axis 1
                     m = admit.reshape((1, -1) + (1,) * (new.ndim - 2))
                     return jnp.where(m, new, old)
@@ -220,6 +307,7 @@ def _engine_jits(cfg, backend: str, sampling: smp.SamplingParams,
                         pf, jax.tree_util.tree_map(jnp.zeros_like, caches))
 
                     def merge(sel):
+                        @jax.named_scope("kv_write")
                         def m(new, old):
                             s = sel.reshape((1, -1) + (1,) * (new.ndim - 2))
                             return jnp.where(s, new, old)
@@ -304,15 +392,17 @@ def auto_page_size(cfg, max_len: int, prefill_len: int,
 
 class _Slot:
     __slots__ = ("rid", "prompt_len", "max_tokens", "eos_id", "generated",
-                 "worst", "mapped")
+                 "worst", "mapped", "admitted_s", "first_token_s")
 
-    def __init__(self, rid, prompt_len, max_tokens, eos_id,
+    def __init__(self, rid, prompt_len, max_tokens, eos_id, admitted_s,
                  worst=0, mapped=0):
         self.rid, self.prompt_len = rid, prompt_len
         self.max_tokens, self.eos_id = max_tokens, eos_id
         self.generated: List[int] = []
         self.worst = worst              # page budget ceil((L+mt-1)/T)
         self.mapped = mapped            # pages currently in the table row
+        self.admitted_s = admitted_s
+        self.first_token_s = 0.0
 
 
 class ServingEngine:
@@ -358,6 +448,17 @@ class ServingEngine:
     valid prefix plus a correction token.  Under greedy sampling the
     emitted stream is bit-identical to the non-speculative engine's on the
     same backend — the parity anchor tests/test_speculative.py pins.
+
+    Tracing: every ``step()`` is four consecutive phase spans, ``engine.
+    plan`` (scheduling, page plan, argument transfers), ``engine.launch``
+    (the jitted call), ``engine.sync`` (reading the sampled tokens back)
+    and ``engine.record`` (tokens to requests, finishing, page release),
+    each with attributes ``tick``, ``kind``, ``live`` and ``admitted``;
+    each adds its wall time to ``stats["{kind}_{phase}_s"]`` for kind
+    ``admit``, ``decode`` or ``speculative``.  ``submit`` and each finished
+    request are spans ``engine.submit`` and ``engine.finish``; garbage
+    collections are ``gc`` spans, their totals in ``stats["gc_pause_s"]``
+    and ``stats["gc_collections"]``.  docs/serving.md lists every counter.
     """
 
     def __init__(self, cfg, dparams, backend: str = "jnp",
@@ -536,15 +637,24 @@ class ServingEngine:
         self._pending: Dict[int, Request] = {}
         self._finished: List[RequestOutput] = []
         self._next_rid = 0
+        self._submitted_s: Dict[int, float] = {}
         self._key = jax.random.PRNGKey(seed)
         self.stats = dict(prefill_launches=0, decode_launches=0,
-                          useful_tokens=0, occupancy_sum=0.0, idle_ticks=0,
+                          useful_tokens=0, occupancy_sum=0.0,
                           prefix_hits=0, zero_prefill_admits=0,
                           cached_tokens=0, deferred_admissions=0,
                           evictions=0, pages_peak=0, draft_launches=0,
                           verify_launches=0, spec_rounds=0,
                           accepted_tokens=0, host_drains=0,
-                          drained_requests=0)
+                          drained_requests=0, admitted=0, queue_wait_s=0.0,
+                          prefill_tokens=0, prefill_slot_tokens=0,
+                          gc_pause_s=0.0, gc_collections=0)
+        self.stats.update({f"{k}_{p}_s": 0.0 for k in _TICK_KINDS
+                           for p in _PHASES})
+        self._ticks = 0
+        self._gc0 = (_GCPauses.pause_s, _GCPauses.collections)
+        self._plan: Optional[_Span] = None
+        self._attrs: dict = {}
         # -- host liveness (drain-on-death) --------------------------------
         # The data axis doubles as the host fleet: host h owns the
         # contiguous slot range fault.owned_slots(h, max_slots, n_hosts).
@@ -563,6 +673,12 @@ class ServingEngine:
     # -- request lifecycle ---------------------------------------------------
     def submit(self, request: Request) -> int:
         """Queue a request for admission; returns its request id."""
+        with self._span("engine.submit", rid=self._next_rid):
+            rid = self._enqueue(request)
+            self._submitted_s[rid] = _clock()
+        return rid
+
+    def _enqueue(self, request: Request) -> int:
         rid = self._next_rid
         toks = np.asarray(request.tokens)
         if toks.ndim != 1:
@@ -681,8 +797,38 @@ class ServingEngine:
         self.stats["pages_peak"] = max(self.stats["pages_peak"],
                                        self.pool.in_use)
 
+    # -- tracing -------------------------------------------------------------
+    def _span(self, name: str, key: Optional[str] = None, **attrs) -> _Span:
+        return _Span(self.stats, name, key, **attrs)
+
+    def _end_plan(self, kind: str, admitted=()) -> None:
+        """Close this step's ``engine.plan`` span under ``kind``; the
+        launch, sync and record spans that follow carry its attributes."""
+        self._attrs = dict(tick=self._ticks, kind=kind,
+                           live=self.live_slots,
+                           admitted=" ".join(str(r) for r in admitted))
+        self._plan.close(f"{kind}_plan_s", **self._attrs)
+
+    def _phase(self, phase: str) -> _Span:
+        return self._span(f"engine.{phase}",
+                          f"{self._attrs['kind']}_{phase}_s", **self._attrs)
+
     # -- scheduler ticks -----------------------------------------------------
     def step(self) -> dict:
+        """One scheduler tick; see :meth:`_step`.  Times its phases into
+        ``stats`` and brings the garbage collector's totals up to date."""
+        self._ticks += 1
+        try:
+            with self._span("engine.plan", tick=self._ticks) as plan:
+                self._plan = plan
+                return self._step()
+        finally:
+            self._plan = None
+            self.stats["gc_pause_s"] = _GCPauses.pause_s - self._gc0[0]
+            self.stats["gc_collections"] = (_GCPauses.collections
+                                            - self._gc0[1])
+
+    def _step(self) -> dict:
         """One scheduler tick = at most one device launch.
 
         Admission has priority: if any slot is free, requests are queued
@@ -708,7 +854,6 @@ class ServingEngine:
         if self._live.any():
             return (self._speculative_tick() if self.speculate_k
                     else self._decode_tick())
-        self.stats["idle_ticks"] += 1
         return {"kind": "idle"}
 
     def _next_key(self):
@@ -801,6 +946,10 @@ class ServingEngine:
         if self.cfg.family == "vlm" and self.cfg.n_prefix_tokens:
             extras["prefix_embeds"] = np.zeros(
                 (B, self.cfg.n_prefix_tokens, self.cfg.d_model), np.float32)
+        now = _clock()
+        self.stats["admitted"] += len(take)
+        self.stats["queue_wait_s"] += sum(now - self._submitted_s[rid]
+                                          for rid in take)
         for slot, rid in zip(free, take):
             req = self._pending.pop(rid)
             toks = np.asarray(req.tokens, np.int32)
@@ -823,13 +972,14 @@ class ServingEngine:
             if self.pool is None:
                 rows[slot, :L] = toks
                 admit[slot] = True
-                self._slots[slot] = _Slot(rid, L, req.max_tokens, req.eos_id)
+                self._slots[slot] = _Slot(rid, L, req.max_tokens, req.eos_id,
+                                          now)
                 self._pos[slot] = L
                 continue
             matched, full_hit, worst, row = plans[rid]
             self._pages[slot, :len(row)] = row
             self._slots[slot] = _Slot(rid, L, req.max_tokens, req.eos_id,
-                                      worst=worst, mapped=len(row))
+                                      now, worst=worst, mapped=len(row))
             self.stats["prefix_hits"] += bool(matched)
             self.stats["cached_tokens"] += len(matched) * T
             if full_hit:
@@ -870,26 +1020,37 @@ class ServingEngine:
                         self.draft_caches)
                 if self.pool is not None:
                     args += (jnp.asarray(wp_flat), jnp.asarray(dwp_flat))
-                self.tokens, self.caches, self.draft_caches = \
-                    self._admit_fn(*args, self._next_key())
             else:
                 args = (self.dparams, batch, jnp.asarray(lens),
                         jnp.asarray(admit), self.tokens, self.caches)
                 if self.pool is not None:
                     args += (jnp.asarray(wp_flat),)
-                self.tokens, self.caches = self._admit_fn(*args,
-                                                          self._next_key())
+            key = self._next_key()
+            self._end_plan("admit", take)
+            with self._phase("launch"):
+                out = self._admit_fn(*args, key)
+            if self.speculate_k:
+                self.tokens, self.caches, self.draft_caches = out
+            else:
+                self.tokens, self.caches = out
             self.stats["prefill_launches"] += 1
             self.stats["useful_tokens"] += int(admit.sum())
-        if boot:
-            tok_np = np.asarray(self.tokens).copy()
-            for slot, last in boot:
-                tok_np[slot, 0] = last
-            self.tokens = jnp.asarray(tok_np)
-        tok_np = np.asarray(self.tokens[:, 0])
-        for slot, rid in zip(free, take):
-            if admit[slot]:
-                self._record(slot, int(tok_np[slot]))
+            # admission padding: prompt tokens against the launch's rows
+            self.stats["prefill_tokens"] += int(lens[admit].sum())
+            self.stats["prefill_slot_tokens"] += B * P
+        else:
+            self._end_plan("admit", take)
+        with self._phase("sync"):
+            if boot:
+                tok_np = np.asarray(self.tokens).copy()
+                for slot, last in boot:
+                    tok_np[slot, 0] = last
+                self.tokens = jnp.asarray(tok_np)
+            tok_np = np.asarray(self.tokens[:, 0])
+        with self._phase("record"):
+            for slot, rid in zip(free, take):
+                if admit[slot]:
+                    self._record(slot, int(tok_np[slot]))
         return ({"kind": "prefill", "admitted": list(take)} if launched
                 else {"kind": "cached", "admitted": list(take)})
 
@@ -935,18 +1096,25 @@ class ServingEngine:
         _, args = self._decode_args()
         return self._step_fn.lower(*args, self._key)
 
-    def _decode_tick(self) -> dict:
+    def _decode_tick(self, kind: str = "decode") -> dict:
+        """One decode launch over the live slots; its phases count under
+        ``kind`` (a speculative round's fallback tick passes its own)."""
         live, args = self._decode_args()
-        self.tokens, self.caches = self._step_fn(*args, self._next_key())
-        self.stats["decode_launches"] += 1
-        n_live = int(live.sum())
-        self.stats["useful_tokens"] += n_live
-        self.stats["occupancy_sum"] += n_live / self.max_slots
-        self._pos[live] += 1
-        self._suppress[live] = False
-        tok_np = np.asarray(self.tokens[:, 0])
-        for slot in np.nonzero(live)[0]:
-            self._record(int(slot), int(tok_np[slot]))
+        key = self._next_key()
+        self._end_plan(kind)
+        with self._phase("launch"):
+            self.tokens, self.caches = self._step_fn(*args, key)
+        with self._phase("sync"):
+            tok_np = np.asarray(self.tokens[:, 0])
+        with self._phase("record"):
+            self.stats["decode_launches"] += 1
+            n_live = int(live.sum())
+            self.stats["useful_tokens"] += n_live
+            self.stats["occupancy_sum"] += n_live / self.max_slots
+            self._pos[live] += 1
+            self._suppress[live] = False
+            for slot in np.nonzero(live)[0]:
+                self._record(int(slot), int(tok_np[slot]))
         return {"kind": "decode", "live": n_live}
 
     def _drain_catchup(self, live: np.ndarray) -> None:
@@ -985,7 +1153,8 @@ class ServingEngine:
         Suppressed slots (full-prefix-hit boot, first tick) fall back to a
         baseline decode tick for everyone: their write position lives in a
         shared read-only radix page, which the W-wide batched scatter
-        cannot skip per-position; the draft catches up next round.
+        cannot skip per-position; the draft catches up next round.  The
+        fallback's catch-up launch and token read count as its plan phase.
         """
         live = self._live.copy()
         k = self.speculate_k
@@ -993,7 +1162,7 @@ class ServingEngine:
             self._drain_catchup(live)
             fed = {int(s): int(np.asarray(self.tokens)[s, 0])
                    for s in np.nonzero(live)[0]}
-            out = self._decode_tick()
+            out = self._decode_tick("speculative")
             for s, t in fed.items():
                 if self._slots[s] is not None:  # draft missed this token
                     self._catchup[s] = True
@@ -1016,77 +1185,92 @@ class ServingEngine:
                         st.mapped += 1
                         self._reserved -= 1
             self._note_pool()
-        self._drain_catchup(live)
-        live_j = jnp.asarray(live)
-        pos0 = self._pos.copy()
-        cur = self.tokens
-        dtoks, dlgs = [], []
-        for j in range(k):
-            args = (self.draft_dparams, cur, self.draft_caches,
-                    jnp.asarray(pos0 + j, jnp.int32), live_j)
+        self._end_plan("speculative")
+        with self._phase("launch"):
+            self._drain_catchup(live)
+            live_j = jnp.asarray(live)
+            pos0 = self._pos.copy()
+            cur = self.tokens
+            dtoks, dlgs = [], []
+            for j in range(k):
+                args = (self.draft_dparams, cur, self.draft_caches,
+                        jnp.asarray(pos0 + j, jnp.int32), live_j)
+                if self.pool is not None:
+                    args += (self._draft_pages,)
+                cur, row, self.draft_caches = self._draft_fn(
+                    *args, self._next_key())
+                self.stats["draft_launches"] += 1
+                dtoks.append(cur)
+                dlgs.append(row)
+            draft_toks = jnp.concatenate(dtoks, axis=1)           # (B, k)
+            draft_logits = jnp.stack(dlgs, axis=1)                # (B, k, V)
+            tokens_w = jnp.concatenate([self.tokens, draft_toks], axis=1)
+            args = (self.dparams, tokens_w, self.caches,
+                    jnp.asarray(pos0, jnp.int32), live_j)
             if self.pool is not None:
-                args += (self._draft_pages,)
-            cur, row, self.draft_caches = self._draft_fn(*args,
-                                                         self._next_key())
-            self.stats["draft_launches"] += 1
-            dtoks.append(cur)
-            dlgs.append(row)
-        draft_toks = jnp.concatenate(dtoks, axis=1)           # (B, k)
-        draft_logits = jnp.stack(dlgs, axis=1)                # (B, k, V)
-        tokens_w = jnp.concatenate([self.tokens, draft_toks], axis=1)
-        args = (self.dparams, tokens_w, self.caches,
-                jnp.asarray(pos0, jnp.int32), live_j)
-        if self.pool is not None:
-            args += (jnp.asarray(self._pages),)
-        accepted, out_tokens, self.caches = self._verify_fn(
-            *args, draft_toks, draft_logits, self._next_key())
-        self.stats["verify_launches"] += 1
-        self.stats["spec_rounds"] += 1
-        acc = np.asarray(accepted)
-        out_np = np.asarray(out_tokens)
-        n_live = int(live.sum())
-        self.stats["occupancy_sum"] += n_live / self.max_slots
-        tok_np = np.asarray(self.tokens).copy()
-        for slot in np.nonzero(live)[0]:
-            m = int(acc[slot])
-            self.stats["accepted_tokens"] += m
-            for j in range(m + 1):
-                self._record(int(slot), int(out_np[slot, j]))
-                self.stats["useful_tokens"] += 1
-                if self._slots[slot] is None:   # finished mid-round: the
-                    break                       # rest of the window drops
-            self._pos[slot] += m + 1
-            if self._slots[slot] is not None:
-                tok_np[slot, 0] = out_np[slot, m]
-                if m == k:
-                    # all accepted: the draft sampled d_k but never fed it
-                    # — its KV at position p+k is owed before next round
-                    self._catchup[slot] = True
-                    self._catchup_tok[slot] = int(out_np[slot, k - 1])
-        self.tokens = jnp.asarray(tok_np)
+                args += (jnp.asarray(self._pages),)
+            accepted, out_tokens, self.caches = self._verify_fn(
+                *args, draft_toks, draft_logits, self._next_key())
+        with self._phase("sync"):
+            acc = np.asarray(accepted)
+            out_np = np.asarray(out_tokens)
+            tok_np = np.asarray(self.tokens).copy()
+        with self._phase("record"):
+            self.stats["verify_launches"] += 1
+            self.stats["spec_rounds"] += 1
+            n_live = int(live.sum())
+            self.stats["occupancy_sum"] += n_live / self.max_slots
+            for slot in np.nonzero(live)[0]:
+                m = int(acc[slot])
+                self.stats["accepted_tokens"] += m
+                for j in range(m + 1):
+                    self._record(int(slot), int(out_np[slot, j]))
+                    self.stats["useful_tokens"] += 1
+                    if self._slots[slot] is None:   # finished mid-round:
+                        break                       # the rest drops
+                self._pos[slot] += m + 1
+                if self._slots[slot] is not None:
+                    tok_np[slot, 0] = out_np[slot, m]
+                    if m == k:
+                        # all accepted: the draft sampled d_k but never fed
+                        # it — its KV at position p+k is owed before next
+                        # round
+                        self._catchup[slot] = True
+                        self._catchup_tok[slot] = int(out_np[slot, k - 1])
+            self.tokens = jnp.asarray(tok_np)
         return {"kind": "speculative", "live": n_live,
                 "accepted": [int(a) for a in acc[live]]}
 
     def _record(self, slot: int, token: int) -> None:
         st = self._slots[slot]
         st.generated.append(token)
+        if len(st.generated) == 1:
+            st.first_token_s = _clock()
         done_len = len(st.generated) >= st.max_tokens
         done_eos = st.eos_id is not None and token == st.eos_id
         if done_len or done_eos:
-            self._finished.append(RequestOutput(
-                rid=st.rid, tokens=np.asarray(st.generated, np.int32),
-                prompt_len=st.prompt_len,
-                finish_reason="eos" if done_eos else "length"))
-            if self.pool is not None:
-                row = self._pages[slot]
-                self.pool.release(int(p) for p in row if p != NULL_PAGE)
-                self._pages[slot, :] = NULL_PAGE
-                self._reserved -= st.worst - st.mapped
-                self._note_pool()
-            self._slots[slot] = None
-            self._live[slot] = False
-            self._catchup[slot] = False
-            self._requests.pop(st.rid, None)
+            reason = "eos" if done_eos else "length"
+            with self._span("engine.finish", rid=st.rid,
+                            tokens=len(st.generated), reason=reason):
+                self._finish(slot, st, reason)
+
+    def _finish(self, slot: int, st: _Slot, reason: str) -> None:
+        self._finished.append(RequestOutput(
+            rid=st.rid, tokens=np.asarray(st.generated, np.int32),
+            prompt_len=st.prompt_len, finish_reason=reason,
+            submitted_s=self._submitted_s.pop(st.rid),
+            admitted_s=st.admitted_s, first_token_s=st.first_token_s,
+            finished_s=_clock()))
+        if self.pool is not None:
+            row = self._pages[slot]
+            self.pool.release(int(p) for p in row if p != NULL_PAGE)
+            self._pages[slot, :] = NULL_PAGE
+            self._reserved -= st.worst - st.mapped
+            self._note_pool()
+        self._slots[slot] = None
+        self._live[slot] = False
+        self._catchup[slot] = False
+        self._requests.pop(st.rid, None)
 
     # -- host failure / drain ------------------------------------------------
     def fail_host(self, host: int) -> None:

@@ -20,9 +20,11 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("kv_gather")
 def gather_pages(pool: jnp.ndarray, pages: jnp.ndarray) -> jnp.ndarray:
     """Materialize per-slot rings: pool ``(NP, *mid, T, F)`` gathered by
     ``pages (B, P)`` -> ``(B, *mid, P*T, F)`` — the dense ring layout the

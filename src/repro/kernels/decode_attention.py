@@ -114,4 +114,5 @@ def decode_attention(q: jnp.ndarray, k_packed: jnp.ndarray,
         out_specs=head,
         out_shape=jax.ShapeDtypeStruct((B, KV, rep, hd), out_dtype),
         interpret=interpret_mode(interpret),
+        name="decode_attention",
     )(pos, q, k_packed, k_scales, v_packed, v_scales)

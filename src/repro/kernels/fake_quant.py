@@ -63,4 +63,5 @@ def fused_mix_2d(w: jnp.ndarray, gamma_hat: jnp.ndarray, alpha: jnp.ndarray,
         out_specs=pl.BlockSpec((bn, bk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, K), jnp.float32),
         interpret=interpret_mode(interpret),
+        name="fused_mix",
     )(w, gamma_hat, alpha.reshape(N, 1))

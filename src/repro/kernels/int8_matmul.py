@@ -147,6 +147,7 @@ def scaled_int8_mm(a: jnp.ndarray, b: jnp.ndarray, sa: jnp.ndarray,
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         interpret=interpret_mode(interpret),
+        name="int8_matmul",
     )(ap, bp, sap.reshape(Mp, 1), sbp.reshape(1, Np))
     return out[:M, :N]
 

@@ -370,6 +370,7 @@ def quant_matmul_fused_3d(x: jnp.ndarray, fused_packed: jnp.ndarray,
             vmem_limit_bytes=_fused_vmem_limit(bm, Kp, tile_n, W, tpb,
                                                x.dtype.itemsize)),
         interpret=interpret_mode(interpret),
+        name="quant_matmul_fused",
     )(x, fused_packed, scales)
     return out.astype(out_dtype)
 
@@ -435,5 +436,6 @@ def quant_matmul_2d(x: jnp.ndarray, packed: jnp.ndarray, scale: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         interpret=interpret_mode(interpret),
+        name="quant_matmul",
     )(x, packed, scale.reshape(1, N))
     return out.astype(out_dtype)

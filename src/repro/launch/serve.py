@@ -95,6 +95,27 @@ def _paged_line(eng):
             f"{eng.kv_bytes_resident()} B vs dense {eng.kv_bytes_dense()} B")
 
 
+def _trace_line(eng):
+    """The engine's tracing counters in one line: host and sync time per
+    decode tick (speculative rounds included), the share of prefill rows
+    that held prompt tokens, the mean queue wait and the GC pauses."""
+    st = eng.stats
+    ticks = st["decode_launches"] + st["spec_rounds"]
+    kinds = ("decode", "speculative")
+    host = sum(st[f"{k}_{p}_s"] for k in kinds
+               for p in ("plan", "launch", "record"))
+    sync = sum(st[f"{k}_sync_s"] for k in kinds)
+    per = (lambda t: 1e3 * t / ticks) if ticks else (lambda t: 0.0)
+    useful = (100.0 * st["prefill_tokens"] / st["prefill_slot_tokens"]
+              if st["prefill_slot_tokens"] else 0.0)
+    wait = (1e3 * st["queue_wait_s"] / st["admitted"]
+            if st["admitted"] else 0.0)
+    return (f"trace:      decode tick host {per(host):.2f} ms + sync "
+            f"{per(sync):.2f} ms, prefill rows useful {useful:.1f}%, "
+            f"queue wait {wait:.1f} ms mean, GC {st['gc_collections']} "
+            f"collections {1e3 * st['gc_pause_s']:.1f} ms")
+
+
 def run_continuous(cfg, dparams, reqs, arrivals, args, mesh=None):
     eng = _engine(cfg, dparams, args, mesh=mesh)
     t0 = time.time()
@@ -134,6 +155,7 @@ def run_continuous(cfg, dparams, reqs, arrivals, args, mesh=None):
           f"decode steps = {steps} launches, slot occupancy {occ:.2f}, "
           f"jit entries {eng.compile_counts()}")
     print(_paged_line(eng))
+    print(_trace_line(eng))
     if eng.speculate_k:
         vl = steps + st["verify_launches"]  # verifier-model launches
         acc = (st["accepted_tokens"] / st["verify_launches"]

@@ -247,6 +247,7 @@ def slot_write_pos(pos: jnp.ndarray, live: Optional[jnp.ndarray],
     return pos if live is None else jnp.where(live, pos, max_len)
 
 
+@jax.named_scope("attn")
 def gqa_decode(p: dict, cfg, x: jnp.ndarray, cache: dict,
                pos: jnp.ndarray, dq_linear,
                live: Optional[jnp.ndarray] = None,
@@ -303,38 +304,43 @@ def gqa_decode(p: dict, cfg, x: jnp.ndarray, cache: dict,
     q, k, v = (shd.replicate_serving(t) for t in (q, k, v))
     # append new kv (int8 per-token or packed channel-wise), one ring
     # index per slot
-    if kv_spec is None:
-        kq, ks = quant_per_token(k.transpose(0, 2, 1, 3))  # (B, KV, 1, hd)
-        vq, vs = quant_per_token(v.transpose(0, 2, 1, 3))
-    else:
-        kq, ks = kvq.quant_channelwise(k.transpose(0, 2, 1, 3), kv_spec)
-        vq, vs = kvq.quant_channelwise(v.transpose(0, 2, 1, 3), kv_spec)
+    with jax.named_scope("kv_write"):
+        if kv_spec is None:
+            kq, ks = quant_per_token(k.transpose(0, 2, 1, 3))  # (B, KV, 1, hd)
+            vq, vs = quant_per_token(v.transpose(0, 2, 1, 3))
+        else:
+            kq, ks = kvq.quant_channelwise(k.transpose(0, 2, 1, 3), kv_spec)
+            vq, vs = kvq.quant_channelwise(v.transpose(0, 2, 1, 3), kv_spec)
+        if pages is None:
+            S = cache["k"].shape[2]
+            bidx = jnp.arange(B)
+            wpos = slot_write_pos(pos, live, S)
+            cache = {
+                "k": cache["k"].at[bidx, :, wpos].set(kq[:, :, 0],
+                                                      mode="drop"),
+                "v": cache["v"].at[bidx, :, wpos].set(vq[:, :, 0],
+                                                      mode="drop"),
+                "k_scale": cache["k_scale"].at[bidx, :, wpos].set(ks[:, :, 0],
+                                                                  mode="drop"),
+                "v_scale": cache["v_scale"].at[bidx, :, wpos].set(vs[:, :, 0],
+                                                                  mode="drop"),
+            }
+        else:
+            NP = cache["k"].shape[0]
+            S = pages.shape[1] * page_size
+            phys, off = paged.write_coords(pos, live, pages, page_size, NP)
+            cache = {
+                "k": cache["k"].at[phys, :, off].set(kq[:, :, 0], mode="drop"),
+                "v": cache["v"].at[phys, :, off].set(vq[:, :, 0], mode="drop"),
+                "k_scale": cache["k_scale"].at[phys, :, off].set(ks[:, :, 0],
+                                                                 mode="drop"),
+                "v_scale": cache["v_scale"].at[phys, :, off].set(vs[:, :, 0],
+                                                                 mode="drop"),
+            }
     if pages is None:
-        S = cache["k"].shape[2]
-        bidx = jnp.arange(B)
-        wpos = slot_write_pos(pos, live, S)
-        cache = {
-            "k": cache["k"].at[bidx, :, wpos].set(kq[:, :, 0], mode="drop"),
-            "v": cache["v"].at[bidx, :, wpos].set(vq[:, :, 0], mode="drop"),
-            "k_scale": cache["k_scale"].at[bidx, :, wpos].set(ks[:, :, 0],
-                                                              mode="drop"),
-            "v_scale": cache["v_scale"].at[bidx, :, wpos].set(vs[:, :, 0],
-                                                              mode="drop"),
-        }
         ki, vi, ksc, vsc = (cache["k"], cache["v"],
                             cache["k_scale"], cache["v_scale"])
     else:
-        NP = cache["k"].shape[0]
-        S = pages.shape[1] * page_size
-        phys, off = paged.write_coords(pos, live, pages, page_size, NP)
-        cache = {
-            "k": cache["k"].at[phys, :, off].set(kq[:, :, 0], mode="drop"),
-            "v": cache["v"].at[phys, :, off].set(vq[:, :, 0], mode="drop"),
-            "k_scale": cache["k_scale"].at[phys, :, off].set(ks[:, :, 0],
-                                                             mode="drop"),
-            "v_scale": cache["v_scale"].at[phys, :, off].set(vs[:, :, 0],
-                                                             mode="drop"),
-        }
         ki = paged.gather_pages(cache["k"], pages)       # (B, KV, S, hd)
         vi = paged.gather_pages(cache["v"], pages)
         ksc = paged.gather_pages(cache["k_scale"], pages)
@@ -410,42 +416,46 @@ def _gqa_decode_multi(p: dict, cfg, x: jnp.ndarray, cache: dict,
         k = L.apply_rope(k, cos, sin, rot)
     # mesh serving: attention operands replicate (identity off-mesh)
     q, k, v = (shd.replicate_serving(t) for t in (q, k, v))
-    if kv_spec is None:
-        kq, ks = quant_per_token(k.transpose(0, 2, 1, 3))  # (B, KV, W, ...)
-        vq, vs = quant_per_token(v.transpose(0, 2, 1, 3))
-    else:
-        kq, ks = kvq.quant_channelwise(k.transpose(0, 2, 1, 3), kv_spec)
-        vq, vs = kvq.quant_channelwise(v.transpose(0, 2, 1, 3), kv_spec)
-    kq, ks = kq.transpose(0, 2, 1, 3), ks.transpose(0, 2, 1, 3)  # (B, W, KV, .)
-    vq, vs = vq.transpose(0, 2, 1, 3), vs.transpose(0, 2, 1, 3)
+    with jax.named_scope("kv_write"):
+        if kv_spec is None:
+            kq, ks = quant_per_token(k.transpose(0, 2, 1, 3))  # (B,KV,W,.)
+            vq, vs = quant_per_token(v.transpose(0, 2, 1, 3))
+        else:
+            kq, ks = kvq.quant_channelwise(k.transpose(0, 2, 1, 3), kv_spec)
+            vq, vs = kvq.quant_channelwise(v.transpose(0, 2, 1, 3), kv_spec)
+        # (B, W, KV, .)
+        kq, ks = kq.transpose(0, 2, 1, 3), ks.transpose(0, 2, 1, 3)
+        vq, vs = vq.transpose(0, 2, 1, 3), vs.transpose(0, 2, 1, 3)
+        if pages is None:
+            S = cache["k"].shape[2]
+            bidx = jnp.arange(B)[:, None]                            # (B, 1)
+            wposk = posk if live is None else jnp.where(live[:, None], posk, S)
+            # advanced indices (bidx, wposk) separated by the KV-head slice ->
+            # their broadcast (B, W) dims lead, so values are (B, W, KV, feat)
+            cache = {
+                "k": cache["k"].at[bidx, :, wposk].set(kq, mode="drop"),
+                "v": cache["v"].at[bidx, :, wposk].set(vq, mode="drop"),
+                "k_scale": cache["k_scale"].at[bidx, :, wposk].set(
+                    ks, mode="drop"),
+                "v_scale": cache["v_scale"].at[bidx, :, wposk].set(
+                    vs, mode="drop"),
+            }
+        else:
+            NP = cache["k"].shape[0]
+            S = pages.shape[1] * page_size
+            phys, off = paged.write_coords(posk, live, pages, page_size, NP)
+            cache = {
+                "k": cache["k"].at[phys, :, off].set(kq, mode="drop"),
+                "v": cache["v"].at[phys, :, off].set(vq, mode="drop"),
+                "k_scale": cache["k_scale"].at[phys, :, off].set(ks,
+                                                                 mode="drop"),
+                "v_scale": cache["v_scale"].at[phys, :, off].set(vs,
+                                                                 mode="drop"),
+            }
     if pages is None:
-        S = cache["k"].shape[2]
-        bidx = jnp.arange(B)[:, None]                            # (B, 1)
-        wposk = posk if live is None else jnp.where(live[:, None], posk, S)
-        # advanced indices (bidx, wposk) separated by the KV-head slice ->
-        # their broadcast (B, W) dims lead, so values are (B, W, KV, feat)
-        cache = {
-            "k": cache["k"].at[bidx, :, wposk].set(kq, mode="drop"),
-            "v": cache["v"].at[bidx, :, wposk].set(vq, mode="drop"),
-            "k_scale": cache["k_scale"].at[bidx, :, wposk].set(ks,
-                                                               mode="drop"),
-            "v_scale": cache["v_scale"].at[bidx, :, wposk].set(vs,
-                                                               mode="drop"),
-        }
         ki, vi, ksc, vsc = (cache["k"], cache["v"],
                             cache["k_scale"], cache["v_scale"])
     else:
-        NP = cache["k"].shape[0]
-        S = pages.shape[1] * page_size
-        phys, off = paged.write_coords(posk, live, pages, page_size, NP)
-        cache = {
-            "k": cache["k"].at[phys, :, off].set(kq, mode="drop"),
-            "v": cache["v"].at[phys, :, off].set(vq, mode="drop"),
-            "k_scale": cache["k_scale"].at[phys, :, off].set(ks,
-                                                             mode="drop"),
-            "v_scale": cache["v_scale"].at[phys, :, off].set(vs,
-                                                             mode="drop"),
-        }
         ki = paged.gather_pages(cache["k"], pages)       # (B, KV, S, hd)
         vi = paged.gather_pages(cache["v"], pages)
         ksc = paged.gather_pages(cache["k_scale"], pages)
@@ -557,6 +567,7 @@ def init_mla_cache(cfg, batch: int, max_len: int,
     }
 
 
+@jax.named_scope("attn")
 def mla_decode(p: dict, cfg, x: jnp.ndarray, cache: dict, pos: jnp.ndarray,
                dq_linear, live: Optional[jnp.ndarray] = None,
                pages: Optional[jnp.ndarray] = None,
@@ -623,34 +634,37 @@ def mla_decode(p: dict, cfg, x: jnp.ndarray, cache: dict, pos: jnp.ndarray,
     q_rope = L.apply_rope(q_rope, cos, sin, rot)
     k_rope_new = L.apply_rope(k_rope_new[:, :, None, :], cos, sin, rot)[:, :, 0]
 
-    if kv_spec is None:
-        qc, qs = quant_per_token(c_kv)
-    else:
-        qc, qs = kvq.quant_channelwise(c_kv, kv_spec)
+    with jax.named_scope("kv_write"):
+        if kv_spec is None:
+            qc, qs = quant_per_token(c_kv)
+        else:
+            qc, qs = kvq.quant_channelwise(c_kv, kv_spec)
+        if pages is None:
+            S = cache["ckv"].shape[1]
+            bidx = jnp.arange(B)
+            wpos = slot_write_pos(pos, live, S)
+            cache = {
+                "ckv": cache["ckv"].at[bidx, wpos].set(qc[:, 0], mode="drop"),
+                "ckv_scale": cache["ckv_scale"].at[bidx, wpos].set(
+                    qs[:, 0], mode="drop"),
+                "krope": cache["krope"].at[bidx, wpos].set(
+                    k_rope_new[:, 0].astype(jnp.bfloat16), mode="drop"),
+            }
+        else:
+            NP = cache["ckv"].shape[0]
+            S = pages.shape[1] * page_size
+            phys, off = paged.write_coords(pos, live, pages, page_size, NP)
+            cache = {
+                "ckv": cache["ckv"].at[phys, off].set(qc[:, 0], mode="drop"),
+                "ckv_scale": cache["ckv_scale"].at[phys, off].set(qs[:, 0],
+                                                                  mode="drop"),
+                "krope": cache["krope"].at[phys, off].set(
+                    k_rope_new[:, 0].astype(jnp.bfloat16), mode="drop"),
+            }
     if pages is None:
-        S = cache["ckv"].shape[1]
-        bidx = jnp.arange(B)
-        wpos = slot_write_pos(pos, live, S)
-        cache = {
-            "ckv": cache["ckv"].at[bidx, wpos].set(qc[:, 0], mode="drop"),
-            "ckv_scale": cache["ckv_scale"].at[bidx, wpos].set(qs[:, 0],
-                                                               mode="drop"),
-            "krope": cache["krope"].at[bidx, wpos].set(
-                k_rope_new[:, 0].astype(jnp.bfloat16), mode="drop"),
-        }
         ckv_i, ckv_s, krope_i = (cache["ckv"], cache["ckv_scale"],
                                  cache["krope"])
     else:
-        NP = cache["ckv"].shape[0]
-        S = pages.shape[1] * page_size
-        phys, off = paged.write_coords(pos, live, pages, page_size, NP)
-        cache = {
-            "ckv": cache["ckv"].at[phys, off].set(qc[:, 0], mode="drop"),
-            "ckv_scale": cache["ckv_scale"].at[phys, off].set(qs[:, 0],
-                                                              mode="drop"),
-            "krope": cache["krope"].at[phys, off].set(
-                k_rope_new[:, 0].astype(jnp.bfloat16), mode="drop"),
-        }
         ckv_i = paged.gather_pages(cache["ckv"], pages)      # (B, S, kvr)
         ckv_s = paged.gather_pages(cache["ckv_scale"], pages)
         krope_i = paged.gather_pages(cache["krope"], pages)
@@ -715,36 +729,39 @@ def _mla_decode_multi(p: dict, cfg, x: jnp.ndarray, cache: dict,
     q_rope = L.apply_rope(q_rope, cos, sin, rot)
     k_rope_new = L.apply_rope(k_rope_new[:, :, None, :], cos, sin, rot)[:, :, 0]
 
-    if kv_spec is None:
-        qc, qs = quant_per_token(c_kv)             # (B, W, kvr) / (B, W, 1)
-    else:
-        qc, qs = kvq.quant_channelwise(c_kv, kv_spec)
+    with jax.named_scope("kv_write"):
+        if kv_spec is None:
+            qc, qs = quant_per_token(c_kv)        # (B, W, kvr) / (B, W, 1)
+        else:
+            qc, qs = kvq.quant_channelwise(c_kv, kv_spec)
+        if pages is None:
+            S = cache["ckv"].shape[1]
+            bidx = jnp.arange(B)[:, None]                            # (B, 1)
+            wposk = posk if live is None else jnp.where(live[:, None], posk, S)
+            # adjacent advanced indices (bidx, wposk) broadcast in place ->
+            # values are (B, W, feat)
+            cache = {
+                "ckv": cache["ckv"].at[bidx, wposk].set(qc, mode="drop"),
+                "ckv_scale": cache["ckv_scale"].at[bidx, wposk].set(
+                    qs, mode="drop"),
+                "krope": cache["krope"].at[bidx, wposk].set(
+                    k_rope_new.astype(jnp.bfloat16), mode="drop"),
+            }
+        else:
+            NP = cache["ckv"].shape[0]
+            S = pages.shape[1] * page_size
+            phys, off = paged.write_coords(posk, live, pages, page_size, NP)
+            cache = {
+                "ckv": cache["ckv"].at[phys, off].set(qc, mode="drop"),
+                "ckv_scale": cache["ckv_scale"].at[phys, off].set(qs,
+                                                                  mode="drop"),
+                "krope": cache["krope"].at[phys, off].set(
+                    k_rope_new.astype(jnp.bfloat16), mode="drop"),
+            }
     if pages is None:
-        S = cache["ckv"].shape[1]
-        bidx = jnp.arange(B)[:, None]                            # (B, 1)
-        wposk = posk if live is None else jnp.where(live[:, None], posk, S)
-        # adjacent advanced indices (bidx, wposk) broadcast in place ->
-        # values are (B, W, feat)
-        cache = {
-            "ckv": cache["ckv"].at[bidx, wposk].set(qc, mode="drop"),
-            "ckv_scale": cache["ckv_scale"].at[bidx, wposk].set(qs,
-                                                                mode="drop"),
-            "krope": cache["krope"].at[bidx, wposk].set(
-                k_rope_new.astype(jnp.bfloat16), mode="drop"),
-        }
         ckv_i, ckv_s, krope_i = (cache["ckv"], cache["ckv_scale"],
                                  cache["krope"])
     else:
-        NP = cache["ckv"].shape[0]
-        S = pages.shape[1] * page_size
-        phys, off = paged.write_coords(posk, live, pages, page_size, NP)
-        cache = {
-            "ckv": cache["ckv"].at[phys, off].set(qc, mode="drop"),
-            "ckv_scale": cache["ckv_scale"].at[phys, off].set(qs,
-                                                              mode="drop"),
-            "krope": cache["krope"].at[phys, off].set(
-                k_rope_new.astype(jnp.bfloat16), mode="drop"),
-        }
         ckv_i = paged.gather_pages(cache["ckv"], pages)      # (B, S, kvr)
         ckv_s = paged.gather_pages(cache["ckv_scale"], pages)
         krope_i = paged.gather_pages(cache["krope"], pages)

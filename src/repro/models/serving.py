@@ -348,6 +348,7 @@ def kv_specs(cfg, kv_bits):
     return kvq.spec_for(kv_bits, cfg.head_dim), None
 
 
+@jax.named_scope("attn")
 def _deployed_attn_full(p, cfg, x, positions, causal=True, enc=None,
                         backend="jnp", build_cache=False, kv_spec=None):
     """Full-seq attention on deployed weights; optionally emit a quantized
@@ -379,6 +380,7 @@ def _deployed_attn_full(p, cfg, x, positions, causal=True, enc=None,
     return y, cache
 
 
+@jax.named_scope("attn")
 def _deployed_mla_full(p, cfg, x, positions, backend="jnp",
                        build_cache=False, kv_spec=None):
     B, S, _ = x.shape
@@ -414,6 +416,7 @@ def _deployed_mla_full(p, cfg, x, positions, backend="jnp",
     return y, cache
 
 
+@jax.named_scope("ffn")
 def _deployed_ffn_full(p, cfg, x, backend="jnp"):
     cd = cfg.cdtype
     dq = _dq(cd, backend)
@@ -468,6 +471,7 @@ def _deployed_moe(p, cfg, x, backend="jnp"):
     return out.reshape(B, S, d)
 
 
+@jax.named_scope("ssm")
 def _deployed_mamba_full(p, cfg, x, backend="jnp", lens=None):
     """Deployed mamba block; returns (y, final ssm state).
 
@@ -552,10 +556,12 @@ def prefill(dparams, cfg, batch, backend: str = "jnp", lens=None,
     gqa_spec, mla_spec = kv_specs(cfg, kv_bits)
     if cfg.family == "audio":
         return _prefill_encdec(dparams, cfg, batch, backend, lens, gqa_spec)
-    x = dparams["embed"][batch["tokens"]].astype(cd)
-    if cfg.n_prefix_tokens and "prefix_embeds" in batch:
-        n = cfg.n_prefix_tokens
-        x = jnp.concatenate([batch["prefix_embeds"].astype(cd), x[:, n:]], 1)
+    with jax.named_scope("embed"):
+        x = dparams["embed"][batch["tokens"]].astype(cd)
+        if cfg.n_prefix_tokens and "prefix_embeds" in batch:
+            n = cfg.n_prefix_tokens
+            x = jnp.concatenate([batch["prefix_embeds"].astype(cd),
+                                 x[:, n:]], 1)
     B, S, _ = x.shape
     positions = jnp.arange(S)
 
@@ -611,8 +617,10 @@ def prefill(dparams, cfg, batch, backend: str = "jnp", lens=None,
         caches["ssm"] = jax.tree_util.tree_map(
             lambda *t: jnp.concatenate(t), *caches["ssm"])
 
-    x = L.apply_norm(x, dparams["ln_f"], cfg.norm)
-    logits = dq_linear(_last_token(x, lens), dparams["lm_head"], cd, backend)
+    with jax.named_scope("lm_head"):
+        x = L.apply_norm(x, dparams["ln_f"], cfg.norm)
+        logits = dq_linear(_last_token(x, lens), dparams["lm_head"], cd,
+                           backend)
     return logits.astype(jnp.float32), caches
 
 
@@ -634,9 +642,10 @@ def _prefill_encdec(dparams, cfg, batch, backend, lens=None, kv_spec=None):
     enc, _ = jax.lax.scan(ebody, enc, dparams["enc_blocks"])
     enc = L.apply_norm(enc, dparams["enc_ln_f"], cfg.norm)
 
-    x = dparams["embed"][batch["tokens"]].astype(cd)
-    B, S, _ = x.shape
-    x = x + L.sinusoidal_positions(S, cfg.d_model).astype(cd)
+    with jax.named_scope("embed"):
+        x = dparams["embed"][batch["tokens"]].astype(cd)
+        B, S, _ = x.shape
+        x = x + L.sinusoidal_positions(S, cfg.d_model).astype(cd)
     pos = jnp.arange(S)
 
     def dbody(h, p):
@@ -654,8 +663,10 @@ def _prefill_encdec(dparams, cfg, batch, backend, lens=None, kv_spec=None):
                                L.apply_norm(h, p["ln3"], cfg.norm), backend)
         return h + f.astype(h.dtype), {"self": c, "cross": cc}
     x, caches = jax.lax.scan(dbody, x, dparams["dec_blocks"])
-    x = L.apply_norm(x, dparams["ln_f"], cfg.norm)
-    logits = dq_linear(_last_token(x, lens), dparams["lm_head"], cd, backend)
+    with jax.named_scope("lm_head"):
+        x = L.apply_norm(x, dparams["ln_f"], cfg.norm)
+        logits = dq_linear(_last_token(x, lens), dparams["lm_head"], cd,
+                           backend)
     return logits.astype(jnp.float32), caches
 
 
@@ -817,6 +828,7 @@ def paged_leaf_mask(cfg):
                      "(see supports_paging)")
 
 
+@jax.named_scope("kv_write")
 def merge_paged_caches(cfg, prefill_caches, caches, admit, wp_flat):
     """Admit a prefill into the paged caches — the paged counterpart of
     ``embed_caches`` + where-merge in the dense engine.
@@ -845,6 +857,7 @@ def merge_paged_caches(cfg, prefill_caches, caches, admit, wp_flat):
                                   prefill_caches, caches)
 
 
+@jax.named_scope("kv_write")
 def embed_caches(prefill_caches, ring):
     """Right-pad the S-deep prefill caches into the max_len ring.
 
@@ -868,6 +881,7 @@ def embed_caches(prefill_caches, ring):
     return jax.tree_util.tree_map(one, prefill_caches, ring)
 
 
+@jax.named_scope("attn")
 def _cross_decode(p, cfg, x, cache, backend, kv_spec=None):
     """Cross-attention decode: query new token against the cached encoder KV.
 
@@ -936,7 +950,8 @@ def decode_step(dparams, cfg, tokens, caches, pos, backend: str = "jnp",
     gqa_spec, mla_spec = kv_specs(cfg, kv_bits)
     cd = cfg.cdtype
     dq = _dq(cd, backend)
-    x = dparams["embed"][tokens].astype(cd)
+    with jax.named_scope("embed"):
+        x = dparams["embed"][tokens].astype(cd)
     B = tokens.shape[0]
     if tokens.shape[1] > 1 and not supports_speculative(cfg):
         raise ValueError(
@@ -1018,6 +1033,7 @@ def decode_step(dparams, cfg, tokens, caches, pos, backend: str = "jnp",
             return h + f.astype(h.dtype), {"self": c2, "cross": c["cross"]}
         x, caches = jax.lax.scan(body, x, (dparams["dec_blocks"], caches))
 
-    x = L.apply_norm(x, dparams["ln_f"], cfg.norm)
-    logits = dq_linear(x, dparams["lm_head"], cd, backend)
+    with jax.named_scope("lm_head"):
+        x = L.apply_norm(x, dparams["ln_f"], cfg.norm)
+        logits = dq_linear(x, dparams["lm_head"], cd, backend)
     return logits.astype(jnp.float32), caches

@@ -174,6 +174,7 @@ def init_ssm_cache(cfg, batch: int) -> dict:
     }
 
 
+@jax.named_scope("ssm")
 def mamba2_decode(p: dict, cfg, x: jnp.ndarray, cache: dict, dq_linear,
                   live: Optional[jnp.ndarray] = None
                   ) -> tuple[jnp.ndarray, dict]:

@@ -7,11 +7,13 @@ with different prompt/output lengths) leaves the fused deployed kernels
 idle behind the shortest-job barrier.  :class:`ServingEngine` redesigns the
 surface around **requests**:
 
-* ``submit`` queues a :class:`Request`; admission pads queued prompts into
-  ONE fixed ``(max_slots, prefill_len)`` prefill launch (per-row true
-  lengths via ``serving.prefill(..., lens=...)``) and merges only the
-  admitted slots' cache rows — in-flight slots are untouched, so prefill of
-  new arrivals interleaves with decode of in-flight ones;
+* ``submit`` queues a :class:`Request`; admission prefills each admitted
+  prompt in its own fixed ``(1, prefill_len)`` launch (right-padded, its
+  true length via ``serving.prefill(..., lens=...)``) and writes only that
+  request's slot — in-flight slots are neither read nor written, so
+  prefill of new arrivals interleaves with decode of in-flight ones, and
+  the chip computes one prompt's rows per admitted request, not
+  ``max_slots`` of them;
 * every decode tick is ONE fixed-width ``decode_step`` launch with a
   **per-slot position vector** and a live mask (freed slots drop their ring
   writes / SSM state updates — models/attention.py, models/ssm.py);
@@ -77,8 +79,8 @@ class Request:
     corresponds to the old lockstep ``generate(gen=G-1)``); ``eos_id``:
     stop early when this id is sampled (still counted in the output);
     ``extras``: per-request prefill arrays keyed like the batch dict
-    (``frames`` for audio, ``prefix_embeds`` for vlm) — rows of slots not
-    being admitted are zero-filled.
+    (``frames`` for audio, ``prefix_embeds`` for vlm) — the admission
+    launch takes them as its one ``(1, ...)`` row.
     """
     tokens: np.ndarray
     max_tokens: int = 16
@@ -217,29 +219,25 @@ def _engine_jits(cfg, backend: str, sampling: smp.SamplingParams,
                         for i, o in enumerate(out))
             return wrapped
 
-        if page_size is None:
-            def _admit(dp, batch, lens, admit, tok_old, caches, key):
-                """One admission: fixed-width prefill + slot-masked merge.
+        def _put_token(tok_old, tok, slot):
+            """The (max_slots, 1) next-token batch with row ``slot`` set to
+            the admitted request's first token."""
+            return jax.lax.dynamic_update_slice_in_dim(
+                tok_old, tok.astype(tok_old.dtype), slot, axis=0)
 
-                ``admit`` (B,) bool selects the slots being (re)filled;
-                their prefill caches are right-padded into the pool ring
-                and merged row-wise, everything else keeps the in-flight
-                state.  Returns the next-token batch (admitted rows
-                freshly sampled from their own last-prompt-token logits,
-                others untouched).
-                """
+        if page_size is None:
+            def _admit(dp, batch, lens, slot, tok_old, caches, key):
+                """One request's admission: a ``(1, prefill_len)`` prefill
+                written into slot ``slot`` (a traced scalar, so one program
+                serves every slot).  Every other slot's cache and token are
+                neither read nor written.  Returns the next-token batch
+                with the admitted row sampled from its last-prompt-token
+                logits, and the caches."""
                 logits, pf = serving.prefill(dp, cfg, batch, backend,
                                              lens=lens, kv_bits=kv_bits)
-                ring = jax.tree_util.tree_map(jnp.zeros_like, caches)
-                emb = serving.embed_caches(pf, ring)
-
-                @jax.named_scope("kv_write")
-                def merge(new, old):  # stacked cache leaves: batch axis 1
-                    m = admit.reshape((1, -1) + (1,) * (new.ndim - 2))
-                    return jnp.where(m, new, old)
-                caches = jax.tree_util.tree_map(merge, emb, caches)
-                tok = smp.sample(logits, sampling, key)          # (B, 1)
-                return jnp.where(admit[:, None], tok, tok_old), caches
+                caches = serving.write_slot(pf, caches, slot)
+                tok = smp.sample(logits, sampling, key)          # (1, 1)
+                return _put_token(tok_old, tok, slot), caches
 
             def _logits(dp, tokens, caches, pos, live):
                 """One decode tick: per-slot positions, live-masked cache."""
@@ -247,23 +245,20 @@ def _engine_jits(cfg, backend: str, sampling: smp.SamplingParams,
                                            backend, live=live,
                                            kv_bits=kv_bits)
         else:
-            def _admit(dp, batch, lens, admit, tok_old, caches, wp_flat,
+            def _admit(dp, batch, lens, slot, tok_old, caches, wp_flat,
                        key):
-                """Paged admission: fixed-width prefill + page scatter.
-
-                ``wp_flat (B * n_prompt_pages,)`` maps each slot's prompt
-                pages to physical pages (out-of-bounds = skip the write:
-                non-admitted slots, junk tails, prefix-shared read-only
-                pages); per-slot leaves (hybrid SSM state, audio cross)
-                still merge on ``admit``.  Same launch shape regardless of
-                how many slots admit — zero recompiles after warmup.
-                """
+                """Paged admission of one request: a ``(1, prefill_len)``
+                prefill whose prompt pages scatter through ``wp_flat
+                (n_prompt_pages,)`` (out-of-bounds = skip the write: junk
+                tails, prefix-shared read-only pages); per-slot leaves
+                (hybrid SSM state, audio cross) are written at ``slot``.
+                One launch shape for every slot and prompt."""
                 logits, pf = serving.prefill(dp, cfg, batch, backend,
                                              lens=lens, kv_bits=kv_bits)
-                caches = serving.merge_paged_caches(cfg, pf, caches, admit,
+                caches = serving.merge_paged_caches(cfg, pf, caches, slot,
                                                     wp_flat)
-                tok = smp.sample(logits, sampling, key)          # (B, 1)
-                return jnp.where(admit[:, None], tok, tok_old), caches
+                tok = smp.sample(logits, sampling, key)          # (1, 1)
+                return _put_token(tok_old, tok, slot), caches
 
             def _logits(dp, tokens, caches, pos, live_write, pages):
                 """One paged decode tick: the ``(B, pages_per_slot)`` page
@@ -290,38 +285,27 @@ def _engine_jits(cfg, backend: str, sampling: smp.SamplingParams,
 
         if speculate_k:
             # Speculative serving replaces the admission executable with a
-            # combined verifier+draft prefill (the draft pool has no prefix
-            # sharing, so it prefills even slots the radix index admits with
-            # zero verifier FLOPs — ``dadmit`` covers ``admit``) and adds the
-            # round executables: a single-token draft step that also returns
-            # the logits row its token was sampled from, and a (k+1)-wide
-            # verify that fuses the multi-token decode with rejection
-            # sampling (api/sampling.speculative_accept).  The baseline
-            # ``step`` stays — it is the suppressed-slot fallback tick.
+            # combined verifier+draft prefill of the same one row (the draft
+            # pool has no prefix sharing, so it prefills even a request the
+            # radix index admits with zero verifier FLOPs: the verifier's
+            # page writes then all carry the out-of-bounds sentinel) and
+            # adds the round executables: a single-token draft step that
+            # also returns the logits row its token was sampled from, and a
+            # (k+1)-wide verify that fuses the multi-token decode with
+            # rejection sampling (api/sampling.speculative_accept).  The
+            # baseline ``step`` stays — it is the suppressed-slot fallback
+            # tick.
             if page_size is None:
-                def _admit_spec(dp, ddp, batch, lens, admit, dadmit, tok_old,
-                                caches, dcaches, key):
+                def _admit_spec(dp, ddp, batch, lens, slot, tok_old, caches,
+                                dcaches, key):
                     logits, pf = serving.prefill(dp, cfg, batch, backend,
                                                  lens=lens, kv_bits=kv_bits)
-                    emb = serving.embed_caches(
-                        pf, jax.tree_util.tree_map(jnp.zeros_like, caches))
-
-                    def merge(sel):
-                        @jax.named_scope("kv_write")
-                        def m(new, old):
-                            s = sel.reshape((1, -1) + (1,) * (new.ndim - 2))
-                            return jnp.where(s, new, old)
-                        return m
-                    caches = jax.tree_util.tree_map(merge(admit), emb, caches)
+                    caches = serving.write_slot(pf, caches, slot)
                     _, dpf = serving.prefill(ddp, cfg, batch, backend,
                                              lens=lens, kv_bits=draft_kv_bits)
-                    demb = serving.embed_caches(
-                        dpf, jax.tree_util.tree_map(jnp.zeros_like, dcaches))
-                    dcaches = jax.tree_util.tree_map(merge(dadmit), demb,
-                                                     dcaches)
+                    dcaches = serving.write_slot(dpf, dcaches, slot)
                     tok = smp.sample(logits, sampling, key)
-                    return (jnp.where(admit[:, None], tok, tok_old), caches,
-                            dcaches)
+                    return _put_token(tok_old, tok, slot), caches, dcaches
 
                 def _draft(ddp, tokens, dcaches, pos, live, key):
                     lg, dcaches = serving.decode_step(
@@ -337,19 +321,18 @@ def _engine_jits(cfg, backend: str, sampling: smp.SamplingParams,
                                                       sampling, key)
                     return acc, out, caches
             else:
-                def _admit_spec(dp, ddp, batch, lens, admit, dadmit, tok_old,
-                                caches, dcaches, wp_flat, dwp_flat, key):
+                def _admit_spec(dp, ddp, batch, lens, slot, tok_old, caches,
+                                dcaches, wp_flat, dwp_flat, key):
                     logits, pf = serving.prefill(dp, cfg, batch, backend,
                                                  lens=lens, kv_bits=kv_bits)
                     caches = serving.merge_paged_caches(cfg, pf, caches,
-                                                        admit, wp_flat)
+                                                        slot, wp_flat)
                     _, dpf = serving.prefill(ddp, cfg, batch, backend,
                                              lens=lens, kv_bits=draft_kv_bits)
                     dcaches = serving.merge_paged_caches(cfg, dpf, dcaches,
-                                                         dadmit, dwp_flat)
+                                                         slot, dwp_flat)
                     tok = smp.sample(logits, sampling, key)
-                    return (jnp.where(admit[:, None], tok, tok_old), caches,
-                            dcaches)
+                    return _put_token(tok_old, tok, slot), caches, dcaches
 
                 def _draft(ddp, tokens, dcaches, pos, live, pages, key):
                     lg, dcaches = serving.decode_step(
@@ -368,7 +351,7 @@ def _engine_jits(cfg, backend: str, sampling: smp.SamplingParams,
                     return acc, out, caches
 
             ent["admit"] = jax.jit(_meshed(_admit_spec, cache_outs=(1, 2)),
-                                   donate_argnums=(7, 8))
+                                   donate_argnums=(6, 7))
             ent["draft_step"] = jax.jit(_meshed(_draft, cache_outs=(2,)),
                                         donate_argnums=(2,))
             ent["verify"] = jax.jit(_meshed(_verify, cache_outs=(2,)),
@@ -415,9 +398,10 @@ class ServingEngine:
         outs = eng.collect()                 # finished RequestOutputs
 
     or, for a whole trace, ``eng.run(requests, arrivals)``.  One engine
-    ``step()`` is at most one device launch (an admission prefill when
-    slots and pages are free and requests are queued, else a decode tick
-    over the live slots), which is what the stats count.
+    ``step()`` is an admission tick when slots and pages are free and
+    requests are queued (one ``(1, prefill_len)`` prefill launch per
+    admitted request), else a decode tick over the live slots (one
+    launch); either ends in one host sync.
 
     ``page_size``: ``"auto"`` (default) pages the KV cache with
     :func:`auto_page_size`; an int forces that page size; ``None`` serves
@@ -647,7 +631,8 @@ class ServingEngine:
                           verify_launches=0, spec_rounds=0,
                           accepted_tokens=0, host_drains=0,
                           drained_requests=0, admitted=0, queue_wait_s=0.0,
-                          prefill_tokens=0, prefill_slot_tokens=0,
+                          prefill_tokens=0, prefill_rows=0,
+                          prefill_slot_tokens=0, admit_ticks=0,
                           gc_pause_s=0.0, gc_collections=0)
         self.stats.update({f"{k}_{p}_s": 0.0 for k in _TICK_KINDS
                            for p in _PHASES})
@@ -829,12 +814,13 @@ class ServingEngine:
                                             - self._gc0[1])
 
     def _step(self) -> dict:
-        """One scheduler tick = at most one device launch.
+        """One scheduler tick = one host sync.
 
         Admission has priority: if any slot is free, requests are queued
         and (paged mode) the head of the queue passes the page-reservation
-        gate, refill (at most one fixed-width prefill launch; fully-cached
-        prompts admit with NO launch).  Otherwise run one decode tick —
+        gate, refill (one width-1 prefill launch per admitted request;
+        fully-cached prompts admit with NO launch).  Otherwise run one
+        decode tick —
         a speculative round when ``speculate_k`` > 0 — over the live
         slots.  Returns a small stats dict (``kind`` in {"prefill",
         "cached", "decode", "speculative", "idle"}).
@@ -885,12 +871,15 @@ class ServingEngine:
         return matched, full_hit, worst
 
     def _admit_tick(self, free: List[int]) -> Optional[dict]:
-        """Admit queued requests into free slots; at most ONE prefill
-        launch.  Paged mode walks the queue strictly FIFO and stops at the
-        first request the page gate rejects (head-of-line waits; decode
-        keeps draining pages).  Returns None when nothing was admitted so
+        """Admit queued requests into free slots: one ``(1, prefill_len)``
+        prefill launch per admitted request that needs one, in slot order,
+        each consuming the caches the previous one returned; fully-cached
+        prompts admit with no launch.  Paged mode walks the queue strictly
+        FIFO and stops at the first request the page gate rejects
+        (head-of-line waits; decode keeps draining pages).  One host sync
+        for the whole tick.  Returns None when nothing was admitted so
         ``step`` falls through to a decode tick."""
-        B, P, T = self.max_slots, self.prefill_len, self.page_size
+        T = self.page_size
         plans = {}
         if self.pool is None:
             take = self.queue[:len(free)]
@@ -928,24 +917,9 @@ class ServingEngine:
                 return None
         del self.queue[:len(take)]
 
-        rows = np.zeros((B, P), np.int32)
-        lens = np.ones(B, np.int32)
-        admit = np.zeros(B, bool)
-        dadmit = np.zeros(B, bool)
-        wp_flat = (None if self.pool is None else
-                   np.full(B * self.n_prompt_pages, self.pool.num_pages,
-                           np.int32))
-        dwp_flat = (None if (self.pool is None or not self.speculate_k) else
-                    np.full(B * self.n_prompt_pages, self._draft_num_pages,
-                            np.int32))
+        launches: List[tuple] = []      # each admission launch's request row
+        prefilled: List[int] = []       # slots whose first token it samples
         boot: List[tuple] = []          # (slot, last prompt token)
-        extras: Dict[str, np.ndarray] = {}
-        if self.cfg.family == "audio":
-            extras["frames"] = np.zeros(
-                (B, self.cfg.encoder_seq, self.cfg.d_model), np.float32)
-        if self.cfg.family == "vlm" and self.cfg.n_prefix_tokens:
-            extras["prefix_embeds"] = np.zeros(
-                (B, self.cfg.n_prefix_tokens, self.cfg.d_model), np.float32)
         now = _clock()
         self.stats["admitted"] += len(take)
         self.stats["queue_wait_s"] += sum(now - self._submitted_s[rid]
@@ -954,27 +928,14 @@ class ServingEngine:
             req = self._pending.pop(rid)
             toks = np.asarray(req.tokens, np.int32)
             L = toks.shape[0]
-            lens[slot] = L
-            for k, v in req.extras.items():
-                extras[k][slot] = v
             self._live[slot] = True
-            if self.speculate_k:
-                # the draft pool never prefix-shares: prefill it for every
-                # admitted slot, including full-hit boots the verifier
-                # admits with zero prefill FLOPs
-                self._catchup[slot] = False
-                dadmit[slot] = True
-                if dwp_flat is not None:
-                    dbase = slot * self.n_prompt_pages
-                    dpage0 = 1 + slot * self.pages_per_slot
-                    for j in range(-(-L // T)):
-                        dwp_flat[dbase + j] = dpage0 + j
+            self._catchup[slot] = False
             if self.pool is None:
-                rows[slot, :L] = toks
-                admit[slot] = True
                 self._slots[slot] = _Slot(rid, L, req.max_tokens, req.eos_id,
                                           now)
                 self._pos[slot] = L
+                launches.append(self._prefill_row(slot, toks, req.extras))
+                prefilled.append(slot)
                 continue
             matched, full_hit, worst, row = plans[rid]
             self._pages[slot, :len(row)] = row
@@ -993,53 +954,41 @@ class ServingEngine:
                 self._suppress[slot] = len(matched) * T == L
                 boot.append((slot, int(toks[-1])))
                 if self.speculate_k:
-                    # the verifier merge ignores this row (admit stays
-                    # False); the draft prefill still needs the prompt
-                    rows[slot, :L] = toks
+                    # the draft pool never prefix-shares: its prefill still
+                    # runs, the verifier writes no page
+                    launches.append(self._prefill_row(slot, toks, req.extras))
             else:
-                rows[slot, :L] = toks
-                admit[slot] = True
                 self._pos[slot] = L
-                base = slot * self.n_prompt_pages
                 # prefill writes only the pages this slot OWNS: matched
                 # prefix pages stay read-only (their bits are already
                 # identical), the tail past ceil(L/T) stays dropped.
-                for j in range(len(matched), len(row)):
-                    wp_flat[base + j] = row[j]
+                launches.append(self._prefill_row(
+                    slot, toks, req.extras,
+                    [(j, row[j]) for j in range(len(matched), len(row))]))
+                prefilled.append(slot)
         if self.pool is not None:
             self._note_pool()
 
-        launched = bool(dadmit.any() if self.speculate_k else admit.any())
-        if launched:
-            batch = {"tokens": jnp.asarray(rows)}
-            batch.update({k: jnp.asarray(v) for k, v in extras.items()})
-            if self.speculate_k:
-                args = (self.dparams, self.draft_dparams, batch,
-                        jnp.asarray(lens), jnp.asarray(admit),
-                        jnp.asarray(dadmit), self.tokens, self.caches,
-                        self.draft_caches)
-                if self.pool is not None:
-                    args += (jnp.asarray(wp_flat), jnp.asarray(dwp_flat))
-            else:
-                args = (self.dparams, batch, jnp.asarray(lens),
-                        jnp.asarray(admit), self.tokens, self.caches)
-                if self.pool is not None:
-                    args += (jnp.asarray(wp_flat),)
-            key = self._next_key()
-            self._end_plan("admit", take)
+        keys = [self._next_key() for _ in launches]
+        self._end_plan("admit", take)
+        if launches:
             with self._phase("launch"):
-                out = self._admit_fn(*args, key)
-            if self.speculate_k:
-                self.tokens, self.caches, self.draft_caches = out
-            else:
-                self.tokens, self.caches = out
-            self.stats["prefill_launches"] += 1
-            self.stats["useful_tokens"] += int(admit.sum())
-            # admission padding: prompt tokens against the launch's rows
-            self.stats["prefill_tokens"] += int(lens[admit].sum())
-            self.stats["prefill_slot_tokens"] += B * P
-        else:
-            self._end_plan("admit", take)
+                for args, key in zip(launches, keys):
+                    out = self._admit_fn(*self._admit_args(args), key)
+                    if self.speculate_k:
+                        self.tokens, self.caches, self.draft_caches = out
+                    else:
+                        self.tokens, self.caches = out
+            P = self.prefill_len
+            self.stats["admit_ticks"] += 1
+            self.stats["prefill_launches"] += len(launches)
+            self.stats["useful_tokens"] += len(prefilled)
+            # admission padding: prompt tokens against the rows launched,
+            # and against one full-width (max_slots, prefill_len) launch
+            self.stats["prefill_tokens"] += sum(self._slots[s].prompt_len
+                                                for s in prefilled)
+            self.stats["prefill_rows"] += len(launches) * P
+            self.stats["prefill_slot_tokens"] += self.max_slots * P
         with self._phase("sync"):
             if boot:
                 tok_np = np.asarray(self.tokens).copy()
@@ -1048,11 +997,64 @@ class ServingEngine:
                 self.tokens = jnp.asarray(tok_np)
             tok_np = np.asarray(self.tokens[:, 0])
         with self._phase("record"):
-            for slot, rid in zip(free, take):
-                if admit[slot]:
-                    self._record(slot, int(tok_np[slot]))
-        return ({"kind": "prefill", "admitted": list(take)} if launched
-                else {"kind": "cached", "admitted": list(take)})
+            for slot in prefilled:
+                self._record(slot, int(tok_np[slot]))
+        return {"kind": "prefill" if launches else "cached",
+                "admitted": list(take)}
+
+    def _prefill_row(self, slot: int, toks: np.ndarray, extras=None,
+                     pages: Sequence[tuple] = ()) -> tuple:
+        """One request's part of an admission launch's arguments: its
+        ``(1, prefill_len)`` right-padded row (and ``(1, ...)`` rows of
+        the family's extras), its length, its slot, and in paged mode the
+        ``(n_prompt_pages,)`` physical page of each prompt page the
+        verifier writes, given as ``(index, page)`` ``pages``; every other
+        entry carries the out-of-bounds sentinel.  A speculative paged
+        engine adds the draft's pages, which it always writes."""
+        L = len(toks)
+        row = np.zeros((1, self.prefill_len), np.int32)
+        row[0, :L] = toks
+        batch = {"tokens": row}
+        if self.cfg.family == "audio":
+            batch["frames"] = np.zeros(
+                (1, self.cfg.encoder_seq, self.cfg.d_model), np.float32)
+        if self.cfg.family == "vlm" and self.cfg.n_prefix_tokens:
+            batch["prefix_embeds"] = np.zeros(
+                (1, self.cfg.n_prefix_tokens, self.cfg.d_model), np.float32)
+        for k, v in (extras or {}).items():
+            batch[k][0] = v
+        out = ({k: jnp.asarray(v) for k, v in batch.items()},
+               jnp.asarray([L], jnp.int32), jnp.asarray(slot, jnp.int32))
+        if self.pool is None:
+            return out
+        wp = np.full(self.n_prompt_pages, self.pool.num_pages, np.int32)
+        for j, page in pages:
+            wp[j] = page
+        out += (jnp.asarray(wp),)
+        if self.speculate_k:
+            n = -(-L // self.page_size)
+            dwp = np.full(self.n_prompt_pages, self._draft_num_pages,
+                          np.int32)
+            dwp[:n] = 1 + slot * self.pages_per_slot + np.arange(n)
+            out += (jnp.asarray(dwp),)
+        return out
+
+    def _admit_args(self, row: tuple) -> tuple:
+        """An admission launch's arguments, less the key: the weights, the
+        request's ``row`` (:meth:`_prefill_row`) and the engine's current
+        tokens and caches."""
+        batch, lens, slot, *pages = row
+        if self.speculate_k:
+            return (self.dparams, self.draft_dparams, batch, lens, slot,
+                    self.tokens, self.caches, self.draft_caches, *pages)
+        return (self.dparams, batch, lens, slot, self.tokens, self.caches,
+                *pages)
+
+    def lower_admit(self):
+        """The engine's jitted admission lowered at its one shape: one
+        request's ``(1, prefill_len)`` row into slot 0."""
+        row = self._prefill_row(0, np.zeros(1, np.int32))
+        return self._admit_fn.lower(*self._admit_args(row), self._key)
 
     def _decode_args(self):
         """The live slots and the decode launch's arguments, less the

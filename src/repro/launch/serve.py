@@ -106,8 +106,8 @@ def _trace_line(eng):
                for p in ("plan", "launch", "record"))
     sync = sum(st[f"{k}_sync_s"] for k in kinds)
     per = (lambda t: 1e3 * t / ticks) if ticks else (lambda t: 0.0)
-    useful = (100.0 * st["prefill_tokens"] / st["prefill_slot_tokens"]
-              if st["prefill_slot_tokens"] else 0.0)
+    useful = (100.0 * st["prefill_tokens"] / st["prefill_rows"]
+              if st["prefill_rows"] else 0.0)
     wait = (1e3 * st["queue_wait_s"] / st["admitted"]
             if st["admitted"] else 0.0)
     return (f"trace:      decode tick host {per(host):.2f} ms + sync "

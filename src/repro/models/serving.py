@@ -829,30 +829,21 @@ def paged_leaf_mask(cfg):
 
 
 @jax.named_scope("kv_write")
-def merge_paged_caches(cfg, prefill_caches, caches, admit, wp_flat):
-    """Admit a prefill into the paged caches — the paged counterpart of
-    ``embed_caches`` + where-merge in the dense engine.
+def merge_paged_caches(cfg, prefill_caches, caches, slot, wp_flat):
+    """Admit one request's prefill into the paged caches — the paged
+    counterpart of :func:`write_slot` in the dense engine.
 
-    Page-pool leaves scatter whole prompt pages through ``wp_flat (B *
-    n_pp,)`` (``cache.paged.scatter_prefill``): non-admitted slots, junk
-    tails past short prompts and prefix-shared (read-only) pages carry the
-    out-of-bounds sentinel and are dropped.  Per-slot leaves (hybrid SSM
-    state, audio cross) right-pad to the ring shape and where-merge on
-    ``admit (B,) bool`` exactly as the dense engine does, preserving
-    non-admitted slots bit-for-bit.
+    Page-pool leaves scatter whole prompt pages through ``wp_flat
+    (n_pp,)`` (``cache.paged.scatter_prefill``): the junk tail past a short
+    prompt and prefix-shared (read-only) pages carry the out-of-bounds
+    sentinel and are dropped.  Per-slot leaves (hybrid SSM state, audio
+    cross) are written at ``slot`` by :func:`write_slot`; every other slot
+    is neither read nor written.
     """
     def one(m, pc, full):
         if m:
             return paged.scatter_prefill(full, pc, wp_flat)
-        if pc.shape != full.shape:
-            diff = [i for i, (a, b) in enumerate(zip(pc.shape, full.shape))
-                    if a != b]
-            assert len(diff) == 1, (pc.shape, full.shape)
-            widths = [(0, 0)] * pc.ndim
-            widths[diff[0]] = (0, full.shape[diff[0]] - pc.shape[diff[0]])
-            pc = jnp.pad(pc, widths)
-        sel = admit.reshape((1, -1) + (1,) * (pc.ndim - 2))
-        return jnp.where(sel, pc.astype(full.dtype), full)
+        return write_slot(pc, full, slot)
     return jax.tree_util.tree_map(one, paged_leaf_mask(cfg),
                                   prefill_caches, caches)
 
@@ -863,11 +854,12 @@ def embed_caches(prefill_caches, ring):
 
     Each leaf differs from its ring counterpart in at most the sequence
     axis; zero-padding IS the empty-slot convention (decode masks by
-    position), so generation really attends to the prompt.  One embedding
-    rule shared by the request-level scheduler's dense mode
-    (api/scheduler.py) and the lockstep oracle loops over
-    ``engine.serving_jits`` (paged engines merge via
-    :func:`merge_paged_caches` instead).
+    position), so generation really attends to the prompt.  ``ring`` only
+    lends its shapes and dtypes (arrays or ``jax.ShapeDtypeStruct``).  One
+    embedding rule shared by the request-level scheduler's admission
+    (:func:`write_slot`, api/scheduler.py) and the lockstep oracle loops
+    over ``engine.serving_jits`` (paged engines scatter their ring pages
+    via :func:`merge_paged_caches` instead).
     """
     def one(pc, full):
         if pc.shape == full.shape:
@@ -879,6 +871,25 @@ def embed_caches(prefill_caches, ring):
         widths[diff[0]] = (0, full.shape[diff[0]] - pc.shape[diff[0]])
         return jnp.pad(pc, widths).astype(full.dtype)
     return jax.tree_util.tree_map(one, prefill_caches, ring)
+
+
+@jax.named_scope("kv_write")
+def write_slot(prefill_caches, caches, slot):
+    """Write one request's prefill caches into slot ``slot`` of the stacked
+    serving caches (batch on axis 1) and leave every other slot as it is.
+
+    The batch-1 prefill is embedded into a one-slot ring
+    (:func:`embed_caches`) and written with one ``dynamic_update_slice``
+    per leaf at the traced ``slot``: the other slots' state is neither
+    read nor rewritten, so one admission program serves every slot.
+    """
+    ring1 = jax.tree_util.tree_map(
+        lambda t: jax.ShapeDtypeStruct(t.shape[:1] + (1,) + t.shape[2:],
+                                       t.dtype), caches)
+    return jax.tree_util.tree_map(
+        lambda new, old: jax.lax.dynamic_update_slice_in_dim(old, new, slot,
+                                                             axis=1),
+        embed_caches(prefill_caches, ring1), caches)
 
 
 @jax.named_scope("attn")

@@ -2,10 +2,11 @@
 
 The continuous-batching redesign (api/scheduler.py) must not change a
 single token: with equal-length synchronized requests ``ServingEngine.run``
-is operand-for-operand a lockstep prefill+decode loop over the shared
-``engine.serving_jits`` executables, so its tokens must be
-**bit-identical**; on staggered traces every request must decode as if it
-were alone in the pool (per-slot positions + live masks isolate slots), so
+is a lockstep prefill+decode loop over the shared ``engine.serving_jits``
+executables, its decode launches operand-for-operand and its admission
+the same row-independent prefill one request per launch, so its tokens
+must be **bit-identical**; on staggered traces every request must decode
+as if it were alone in the pool (per-slot positions + live masks isolate slots), so
 each output must match a per-request lockstep generate token-for-token and
 be independent of co-scheduled slot contents.  The engines here run the
 default **paged** KV cache (PR 6) where the family supports it — the
@@ -42,16 +43,24 @@ def _setup(arch, seed=0, **overrides):
 
 
 def _lockstep_generate(cfg, dp, batch, gen, max_len, backend="jnp",
-                       sampling=GREEDY, key=None):
+                       sampling=GREEDY, key=None, prefill_rows=False):
     """Lockstep oracle: one shared prefill, then ``gen`` synchronized
     decode steps over the module-cached ``serving_jits`` executables —
     the ~10-line loop that replaced the removed ``ServingSession``.
-    Returns tokens (B, gen+1) including the prefill-sampled one."""
+    ``prefill_rows`` prefills each row alone, as the engine's admission
+    does.  Returns tokens (B, gen+1) including the prefill-sampled one."""
     fns = serving_jits(cfg, backend)
     B, S = batch["tokens"].shape
     if sampling.kind != "greedy" and key is None:
         key = jax.random.PRNGKey(0)
-    logits, pf = fns["prefill"](dp, batch)
+    if prefill_rows:
+        rows = [fns["prefill"](dp, {k: v[i:i + 1] for k, v in batch.items()})
+                for i in range(B)]
+        logits = jnp.concatenate([lg for lg, _ in rows])
+        pf = jax.tree_util.tree_map(lambda *t: jnp.concatenate(t, axis=1),
+                                    *[c for _, c in rows])
+    else:
+        logits, pf = fns["prefill"](dp, batch)
     caches = serving.embed_caches(pf, serving.init_caches(cfg, B, max_len))
     if key is not None:
         key, k0 = jax.random.split(key)
@@ -90,12 +99,18 @@ def test_sync_requests_bit_identical_to_lockstep(arch, backend):
     cfg, dp = _setup(arch)
     B, S, G = (2, 4, 3) if backend == "pallas" else (2, 8, 6)
     toks = _prompts(cfg, (B, S), seed=1)
+    # MoE couples co-batched rows through expert-capacity drops, so its
+    # oracle prefills each row alone, as admission does; the other
+    # families' rows are independent and the shared prefill gives the
+    # same bits
     ref = _lockstep_generate(cfg, dp, {"tokens": jnp.asarray(toks)},
-                             gen=G - 1, max_len=S + G, backend=backend)
+                             gen=G - 1, max_len=S + G, backend=backend,
+                             prefill_rows=cfg.family == "moe")
     eng = ServingEngine(cfg, dp, backend=backend, max_slots=B,
                         max_len=S + G, prefill_len=S)
     outs = eng.run([Request(toks[i], max_tokens=G) for i in range(B)])
-    assert eng.stats["prefill_launches"] == 1   # one shared admission
+    assert eng.stats["admit_ticks"] == 1        # one shared admission tick
+    assert eng.stats["prefill_launches"] == B   # one launch per request
     for i in range(B):
         np.testing.assert_array_equal(outs[i].tokens, np.asarray(ref[i]))
 
@@ -198,6 +213,134 @@ def test_zero_recompiles_after_warmup():
     assert eng2.stats["prefill_launches"] >= 2   # slots really were refilled
     assert eng2.compile_counts() == warm, \
         "slot-pool serving recompiled after warmup"
+
+
+# ---------------------------------------------------------------------------
+# Admission: one (1, prefill_len) launch per admitted request
+# ---------------------------------------------------------------------------
+
+ADMIT_FAMILIES = [
+    ("qwen1.5-4b", "auto"),         # dense, paged
+    ("qwen1.5-4b", None),           # dense, dense rings
+    ("deepseek-v3-671b", "auto"),   # moe + mla, paged
+    ("mamba2-780m", None),          # ssm
+    ("zamba2-1.2b", "auto"),        # hybrid: paged rings + per-slot state
+]
+DENSE_FAMILIES = ["qwen1.5-4b", "deepseek-v3-671b", "mamba2-780m",
+                  "zamba2-1.2b"]
+ADMIT = dict(max_slots=4, max_len=24, prefill_len=8)
+
+
+_ADMIT_CFGS = {}
+
+
+def _admit_engine(arch, page_size):
+    """A 4-slot engine over a config object these tests alone use: the jit
+    cache is keyed on cfg id, so its programs hold the ``ADMIT`` shapes
+    only, and the compile counts other tests assert see none of them."""
+    if arch not in _ADMIT_CFGS:
+        cfg, dp = _setup(arch)
+        _ADMIT_CFGS[arch] = (dataclasses.replace(cfg), dp)
+    cfg, dp = _ADMIT_CFGS[arch]
+    return ServingEngine(cfg, dp, backend="jnp", page_size=page_size,
+                         **ADMIT)
+
+
+def _leaves(tree):
+    return [np.asarray(t).copy() for t in jax.tree_util.tree_leaves(tree)]
+
+
+def _fill_two_slots(eng):
+    """Slots 0 and 1 admitted and decoded once, so they hold state."""
+    for n in (6, 4):
+        eng.submit(Request(_prompts(eng.cfg, (n,), seed=n), max_tokens=8))
+    assert eng.step()["kind"] == "prefill"
+    assert eng.step()["kind"] == "decode"
+
+
+@pytest.mark.parametrize("arch,page_size", ADMIT_FAMILIES)
+def test_admission_tick_launches_once_per_request(arch, page_size):
+    """A tick admitting three requests makes three (1, prefill_len)
+    launches and one host sync, and the engine compiles one admission
+    program."""
+    eng = _admit_engine(arch, page_size)
+    admit_fn, phase = eng._admit_fn, eng._phase
+    launches, phases = [], []
+
+    def spy(*args):
+        launches.append(args[1]["tokens"].shape)
+        return admit_fn(*args)
+
+    eng._admit_fn = spy
+    eng._phase = lambda p: phases.append(p) or phase(p)
+    for n in (3, 8, 5):
+        eng.submit(Request(_prompts(eng.cfg, (n,), seed=n), max_tokens=3))
+    out = eng.step()
+    assert out["kind"] == "prefill" and len(out["admitted"]) == 3
+    assert launches == [(1, ADMIT["prefill_len"])] * 3
+    assert phases == ["launch", "sync", "record"]
+    assert eng.stats["admit_ticks"] == 1
+    assert eng.stats["prefill_launches"] == 3
+    assert eng.stats["prefill_tokens"] == 16
+    assert eng.stats["prefill_rows"] == 3 * ADMIT["prefill_len"]
+    assert eng.stats["prefill_slot_tokens"] == (ADMIT["max_slots"]
+                                                * ADMIT["prefill_len"])
+    eng._admit_fn, eng._phase = admit_fn, phase
+    while eng.has_work():
+        eng.step()
+    assert [len(o.tokens) for o in eng.collect()] == [3, 3, 3]
+    assert eng.compile_counts() == {"admit": 1, "step": 1}
+
+
+@pytest.mark.parametrize("arch", DENSE_FAMILIES)
+def test_admission_leaves_other_slots_untouched(arch):
+    """Dense rings and SSM state: an admission into slot 2 leaves every
+    other slot's cache leaves and pending token bit-identical."""
+    eng = _admit_engine(arch, None)
+    _fill_two_slots(eng)
+    caches, tok = _leaves(eng.caches), np.asarray(eng.tokens).copy()
+    eng.submit(Request(_prompts(eng.cfg, (7,), seed=7), max_tokens=4))
+    assert eng.step()["kind"] == "prefill"
+    assert eng._slots[2] is not None and eng._slots[3] is None
+    other = [0, 1, 3]
+    for a, b in zip(caches, _leaves(eng.caches)):
+        np.testing.assert_array_equal(a[:, other], b[:, other])
+    np.testing.assert_array_equal(tok[other], np.asarray(eng.tokens)[other])
+    assert any(not np.array_equal(a[:, 2], b[:, 2])
+               for a, b in zip(caches, _leaves(eng.caches)))
+
+
+@pytest.mark.parametrize("arch", DENSE_FAMILIES)
+def test_admitted_slot_equals_prefill_alone(arch):
+    """The admitted slot holds exactly ``serving.prefill`` of its prompt
+    alone, embedded into a one-slot ring by ``serving.embed_caches``, and
+    its pending token is that prefill's greedy token."""
+    eng = _admit_engine(arch, None)
+    cfg, dp = eng.cfg, eng.dparams
+    eng.submit(Request(_prompts(cfg, (6,), seed=6), max_tokens=4))
+    eng.step()
+    toks = _prompts(cfg, (5,), seed=5)
+    eng.submit(Request(toks, max_tokens=4))
+    eng.step()
+    row = np.zeros((1, ADMIT["prefill_len"]), np.int32)
+    row[0, :5] = toks
+    logits, pf = serving_jits(cfg, "jnp")["prefill"](
+        dp, {"tokens": jnp.asarray(row)}, jnp.asarray([5], jnp.int32))
+    want = serving.embed_caches(
+        pf, serving.init_caches(cfg, 1, ADMIT["max_len"]))
+    for w, got in zip(_leaves(want), _leaves(eng.caches)):
+        np.testing.assert_array_equal(got[:, 1], w[:, 0])
+    assert int(np.asarray(eng.tokens)[1, 0]) == int(
+        np.argmax(np.asarray(logits)[0, -1]))
+
+
+@pytest.mark.parametrize("arch,page_size", ADMIT_FAMILIES)
+def test_admission_program_takes_one_row(arch, page_size):
+    """The admission program is lowered at a (1, prefill_len) batch."""
+    eng = _admit_engine(arch, page_size)
+    args, _ = eng.lower_admit().args_info
+    assert args[1]["tokens"].shape == (1, ADMIT["prefill_len"])
+    assert args[2].shape == (1,)                    # its length
 
 
 def test_engine_construction_reuses_module_jits():

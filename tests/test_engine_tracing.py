@@ -15,7 +15,6 @@ import tempfile
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax._src import source_info_util
@@ -260,20 +259,9 @@ class _NoScopes:
         monkeypatch.setattr(cls, "__exit__", lambda self, *a: None)
 
 
-def _lower_admit(eng):
-    """The engine's admission program lowered at its own shapes (paged,
-    not speculative)."""
-    B, P = eng.max_slots, eng.prefill_len
-    return eng._admit_fn.lower(
-        eng.dparams, {"tokens": jnp.zeros((B, P), jnp.int32)},
-        jnp.ones(B, jnp.int32), jnp.zeros(B, bool), eng.tokens, eng.caches,
-        jnp.full(B * eng.n_prompt_pages, eng.pool.num_pages, jnp.int32),
-        eng._key)
-
-
 def _compiled(eng, program):
     jax.clear_caches()
-    low = eng.lower_step() if program == "decode" else _lower_admit(eng)
+    low = eng.lower_step() if program == "decode" else eng.lower_admit()
     return low.compile().as_text()
 
 

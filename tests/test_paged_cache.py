@@ -25,7 +25,11 @@ from repro.api.scheduler import Request, ServingEngine, auto_page_size
 from repro.cache import (DoubleFree, NULL_PAGE, PageAllocator, PageError,
                          PagesExhausted, PagePool, RadixIndex, gather_pages,
                          scatter_prefill, write_coords)
-from test_continuous_batching import STAGGER, _setup, _stagger_trace
+from repro.api.engine import serving_jits
+from repro.models import serving
+from test_continuous_batching import (ADMIT, STAGGER, _admit_engine,
+                                      _fill_two_slots, _leaves, _setup,
+                                      _stagger_trace)
 
 
 # ---------------------------------------------------------------------------
@@ -319,6 +323,66 @@ def test_paged_engine_bit_identical_to_dense(arch, backend):
     # identical schedule, launch for launch
     for k in ("prefill_launches", "decode_launches", "useful_tokens"):
         assert paged_eng.stats[k] == dense_eng.stats[k]
+
+
+PAGED_FAMILIES = ["qwen1.5-4b", "deepseek-v3-671b", "zamba2-1.2b"]
+
+
+def _prompt_of(cfg, n):
+    return np.random.default_rng(n).integers(
+        0, cfg.vocab_size, n).astype(np.int32)
+
+
+@pytest.mark.parametrize("arch", PAGED_FAMILIES)
+def test_paged_admission_leaves_other_slots_untouched(arch):
+    """Paged: an admission writes only the pages its slot owns (and, in
+    the hybrid family, its slot of the per-slot SSM state); every other
+    page and slot, and every other pending token, is bit-identical."""
+    eng = _admit_engine(arch, "auto")
+    _fill_two_slots(eng)
+    before, tok = _leaves(eng.caches), np.asarray(eng.tokens).copy()
+    eng.submit(Request(_prompt_of(eng.cfg, 7), max_tokens=4))
+    assert eng.step()["kind"] == "prefill"
+    own = [int(p) for p in eng._pages[2] if p != NULL_PAGE]
+    assert own
+    mask = jax.tree_util.tree_leaves(serving.paged_leaf_mask(eng.cfg))
+    for paged, a, b in zip(mask, before, _leaves(eng.caches)):
+        keep = np.setdiff1d(np.arange(a.shape[1]), own if paged else [2])
+        np.testing.assert_array_equal(a[:, keep], b[:, keep])
+    np.testing.assert_array_equal(tok[[0, 1, 3]],
+                                  np.asarray(eng.tokens)[[0, 1, 3]])
+
+
+@pytest.mark.parametrize("arch", PAGED_FAMILIES)
+def test_paged_admitted_slot_equals_prefill_alone(arch):
+    """Paged: the admitted slot's pages, gathered back into its ring, and
+    its per-slot leaves hold ``serving.prefill`` of its prompt alone,
+    embedded by ``serving.embed_caches``, over the prompt pages it
+    owns."""
+    eng = _admit_engine(arch, "auto")
+    cfg = eng.cfg
+    eng.submit(Request(_prompt_of(cfg, 6), max_tokens=4))
+    eng.step()
+    toks = _prompt_of(cfg, 5)
+    eng.submit(Request(toks, max_tokens=4))
+    eng.step()
+    row = np.zeros((1, ADMIT["prefill_len"]), np.int32)
+    row[0, :5] = toks
+    _, pf = serving_jits(cfg, "jnp")["prefill"](
+        eng.dparams, {"tokens": jnp.asarray(row)}, jnp.asarray([5], jnp.int32))
+    want = _leaves(serving.embed_caches(
+        pf, serving.init_caches(cfg, 1, ADMIT["max_len"])))
+    pages = jnp.asarray(eng._pages[1:2])
+    n = -(-5 // eng.page_size) * eng.page_size     # positions it wrote
+    mask = jax.tree_util.tree_leaves(serving.paged_leaf_mask(cfg))
+    for paged, w, got in zip(mask, want, _leaves(eng.caches)):
+        if not paged:
+            np.testing.assert_array_equal(got[:, 1], w[:, 0])
+            continue
+        for x in range(got.shape[0]):
+            ring = np.asarray(gather_pages(jnp.asarray(got[x]), pages))
+            np.testing.assert_array_equal(ring[0, ..., :n, :],
+                                          w[x, 0, ..., :n, :])
 
 
 def test_paged_zero_recompiles_after_warmup():
